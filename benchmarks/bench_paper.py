@@ -460,6 +460,8 @@ def main() -> None:
                     help="JSON output path (default BENCH_smoke.json / "
                          "BENCH_full.json)")
     args = ap.parse_args()
+    from repro.cache import configure_compile_cache
+    configure_compile_cache()
     if args.smoke:
         run_smoke(args.out or "BENCH_smoke.json")
         return

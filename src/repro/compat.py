@@ -1,72 +1,31 @@
-"""Version-compat shims for JAX APIs that drifted across releases.
+"""The mesh and shard_map calls every sharded path in the repo makes.
 
-Tested floor: ``jax>=0.4.30`` (declared in pyproject.toml).  Three APIs
-this codebase needs moved between 0.4.x and 0.5+:
-
-* ``jax.make_mesh(..., axis_types=...)`` — ``axis_types`` /
-  ``jax.sharding.AxisType`` only exist on JAX >= 0.5; ``jax.make_mesh``
-  itself only since 0.4.35.
-* ``jax.sharding.get_abstract_mesh()`` — JAX >= 0.5 only.
-* ``jax.shard_map`` — graduated from ``jax.experimental.shard_map``; the
-  ``check_rep`` kwarg was renamed ``check_vma`` along the way.
-
-Every mesh/shard-map construction in the repo goes through this module
-so the fallback logic lives in exactly one place.
+Written for the installed JAX (0.9.0): meshes pin ``AxisType.Auto`` on
+every axis, so today's sharding behaviour holds even if JAX's default
+ever flips to Explicit, and ``shard_map`` spells its replication check
+``check_vma``.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    JAX >= 0.5: ``jax.make_mesh(shape, names, axis_types=(Auto,) * n)``
-    (pins today's behaviour even if the default ever flips to Explicit).
-    0.4.35 <= JAX < 0.5: ``jax.make_mesh`` without ``axis_types``.
-    JAX < 0.4.35: plain ``Mesh`` over ``mesh_utils.create_device_mesh``.
-    """
-    shape = tuple(shape)
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
+              devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types, over ``devices`` (default:
+    all of them)."""
     axis_names = tuple(axis_names)
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            return mk(shape, axis_names,
-                      axis_types=(axis_type.Auto,) * len(axis_names))
-        return mk(shape, axis_names)
-    from jax.experimental import mesh_utils
-
-    return Mesh(mesh_utils.create_device_mesh(shape), axis_names)
-
-
-def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()`` or ``None`` where absent."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    return fn() if fn is not None else None
-
-
-def _resolve_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
+    return jax.make_mesh(tuple(shape), axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs,
-              check_rep: Optional[bool] = False):
-    """Apply ``shard_map`` with the replication-check flag this JAX spells
-    ``check_rep`` (<= 0.6) or ``check_vma`` (>= 0.7)."""
-    sm = _resolve_shard_map()
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        kwargs["check_vma"] = check_rep
-    elif "check_rep" in params:
-        kwargs["check_rep"] = check_rep
-    return sm(f, **kwargs)
+              check_vma: bool = False):
+    """``jax.shard_map``; the replication check is off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

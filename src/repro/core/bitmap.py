@@ -29,11 +29,13 @@ host mirror, kept for packing-time code and tests.
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
 from typing import Dict, Hashable, List, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 WORD_BITS = 32
@@ -91,6 +93,36 @@ def chunk_width_for(words_per_pair: int, base_chunk: int,
             width = b
     floor = min(int(base_chunk), bucket_table[-1])
     return max(width, floor)
+
+
+# Row-sized device buffers a fused bitmap dispatch keeps live per pair:
+# the gathered U and V rows, the child row Z and the scatter's update.
+# The TPU compiler measures 4.07 rows per pair at kosarak width (4096
+# pairs x 31219 words/row: 1.94 GiB of temporaries).
+DISPATCH_ROWS_PER_PAIR = 4
+
+
+def device_memory_bytes() -> int:
+    """Memory of the default device: the accelerator's ``bytes_limit``,
+    or host RAM for the CPU backend (which reports no limit)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    if limit:
+        return int(limit)
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+
+
+def hbm_pair_cap(words_per_pair: int, memory_bytes: int,
+                 bucket_table: Sequence[int]) -> int:
+    """Widest bucketed pair chunk whose fused-dispatch temporaries
+    (``DISPATCH_ROWS_PER_PAIR`` rows of ``words_per_pair`` uint32 words
+    per pair) fit in half of ``memory_bytes`` — the other half holds the
+    row store and the pipeline's in-flight outputs.  Never below the
+    smallest bucket.  At kosarak width on a 15.75 GiB v5e this is 16384
+    pairs, where an uncapped 65536-pair chunk needs 30.9 GB."""
+    per_pair = DISPATCH_ROWS_PER_PAIR * 4 * max(1, int(words_per_pair))
+    fits = [b for b in bucket_table if b * per_pair <= memory_bytes // 2]
+    return fits[-1] if fits else bucket_table[0]
 
 
 def nl_pad_len(n: int) -> int:
@@ -193,8 +225,11 @@ def suffix_popcounts_np(bitmaps: np.ndarray) -> np.ndarray:
     return out
 
 
+@jax.jit
 def suffix_popcounts(bitmaps: jnp.ndarray) -> jnp.ndarray:
-    """Device version of :func:`suffix_popcounts_np`."""
+    """Device version of :func:`suffix_popcounts_np`.  Jitted so the
+    SWAR steps fuse: run op by op over a full slab (1 GB at
+    kosarak-paper), each step would hold a slab-sized temporary."""
     per_block = popcount32(bitmaps).sum(axis=-1).astype(jnp.int32)
     rev = jnp.cumsum(per_block[:, ::-1], axis=1)[:, ::-1]
     zeros = jnp.zeros((bitmaps.shape[0], 1), dtype=jnp.int32)

@@ -54,6 +54,8 @@ def main() -> None:
     ap.add_argument("--json-out", default="",
                     help="write all frequent itemsets to a JSON file")
     args = ap.parse_args()
+    from repro.cache import configure_compile_cache
+    configure_compile_cache()
 
     if args.dataset:
         from repro.data import make_dataset

@@ -123,7 +123,7 @@ def make_mining_round(mesh: Mesh, *, pair_chunk: int = 2048):
     return shard_map(
         mining_round, mesh=mesh,
         in_specs=(P(None, tid_spec, None), P(None, None), P(None)),
-        out_specs=(P(None), P(None)), check_rep=False)
+        out_specs=(P(None), P(None)), check_vma=False)
 
 
 def make_mining_round_v2(mesh: Mesh, *, pair_chunk: int = 2048):
@@ -174,7 +174,7 @@ def make_mining_round_v2(mesh: Mesh, *, pair_chunk: int = 2048):
         mining_round, mesh=mesh,
         in_specs=(P(None, tid_spec, None), P(None, tid_spec), P(None, None),
                   P(None)),
-        out_specs=(P(None), P(None)), check_rep=False)
+        out_specs=(P(None), P(None)), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -271,6 +271,12 @@ class DistributedMiner(BitmapMiner):
         # the per-device VMEM budget divides by n_cls (satellite 6) —
         # ceil so the width never overshoots the budget.
         return -(-(bdb.n_blocks * self.block_words) // self.n_cls)
+
+    def _device_words_per_pair(self, store: DeviceRowStore) -> int:
+        # A device holds one block shard of each row; the cls all-gather
+        # brings the whole chunk's child slices to every device, so the
+        # bound does not divide by n_cls.
+        return -(-store.words_per_row // store.n_shards)
 
     def _make_store(self, bdb: BitmapDB) -> DeviceRowStore:
         return DeviceRowStore(

@@ -80,7 +80,8 @@ import jax.numpy as jnp
 
 from repro.core.bitmap import (BITMAP_REF_ROW_WORDS, BitmapDB,
                                DEFAULT_BLOCK_WORDS, PAIR_CHUNK_BUCKETS,
-                               bucket_pad, chunk_width_for)
+                               bucket_pad, chunk_width_for,
+                               device_memory_bytes, hbm_pair_cap)
 from repro.core.frontier import (Child, ClassNode, EngineAccounting,
                                  FrontierScheduler)
 from repro.core.guards import host_sync
@@ -324,14 +325,19 @@ class BitmapMiner:
         self._store = store
         self._out = out
         self._stats = stats
+        # No chunk may form a dispatch whose temporaries outgrow the
+        # device: bound the width by memory at this run's row width.
+        cap = hbm_pair_cap(self._device_words_per_pair(store),
+                           device_memory_bytes(), _PAIR_BUCKETS)
+        pair_chunk = min(self.pair_chunk, cap)
         # Autotuned chunk width: every bitmap pair in a run moves the
         # same per-pair word mass, so the width is one run-wide value
         # (the N-list engine's is per length bucket).
-        self._chunk_width = (chunk_width_for(
-            self._autotune_words_per_pair(bdb), self.pair_chunk,
-            _PAIR_BUCKETS, BITMAP_REF_ROW_WORDS)
+        self._chunk_width = (min(chunk_width_for(
+            self._autotune_words_per_pair(bdb), pair_chunk,
+            _PAIR_BUCKETS, BITMAP_REF_ROW_WORDS), cap)
             if self.autotune_chunk else None)
-        sched = FrontierScheduler(self, self.pair_chunk,
+        sched = FrontierScheduler(self, pair_chunk,
                                   inflight=self.inflight,
                                   drain_target=self._chunk_width)
         sched.run(root)
@@ -347,6 +353,12 @@ class BitmapMiner:
         the chunk, so at equal per-device VMEM the chunk can be n_cls
         times wider (ISSUE 9 satellite 6)."""
         return bdb.n_blocks * self.block_words
+
+    def _device_words_per_pair(self, store: DeviceRowStore) -> int:
+        """Words of one slab row on one device — the unit of the memory
+        bound on the pair chunk (the distributed miner's rows are split
+        over its block shards)."""
+        return store.words_per_row
 
     def _make_store(self, bdb: BitmapDB) -> DeviceRowStore:
         """Allocate the device slab.  Subclasses (the distributed miner)

@@ -48,22 +48,13 @@ def purity_guard_active() -> bool:
     return _DEPTH > 0
 
 
-def _d2h_guard(level: str):
-    # jax.transfer_guard_device_to_host is stable API since jax 0.3;
-    # the getattr shim keeps ancient/forked builds importable.
-    g = getattr(jax, "transfer_guard_device_to_host", None)
-    if g is None:                         # pragma: no cover
-        return contextlib.nullcontext()
-    return g(level)
-
-
 @contextlib.contextmanager
 def device_purity_guard():
     """Disallow unannotated device->host transfers in this region."""
     global _DEPTH
     _DEPTH += 1
     try:
-        with _d2h_guard("disallow"):
+        with jax.transfer_guard_device_to_host("disallow"):
             yield
     finally:
         _DEPTH -= 1
@@ -77,7 +68,7 @@ def host_sync(why: str):
     global _DEPTH
     saved, _DEPTH = _DEPTH, 0
     try:
-        with _d2h_guard("allow"):
+        with jax.transfer_guard_device_to_host("allow"):
             yield
     finally:
         _DEPTH = saved

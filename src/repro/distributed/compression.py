@@ -57,7 +57,7 @@ def compressed_crosspod_allreduce(tree, mesh, pod_axis: str = "pod"):
     constrain the loss's batch to "data" only and call this on the grads.
     """
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     if pod_axis not in mesh.axis_names:
@@ -67,7 +67,7 @@ def compressed_crosspod_allreduce(tree, mesh, pod_axis: str = "pod"):
         spec = P(*([None] * x.ndim))
 
         @partial(shard_map, mesh=mesh, in_specs=spec, out_specs=spec,
-                 check_rep=False)
+                 check_vma=False)
         def red(v):
             n = jax.lax.psum(jnp.ones((), jnp.float32), pod_axis)
             return compressed_psum_int8(v, pod_axis) / n

@@ -20,7 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
 
 AxisName = Optional[Union[str, Tuple[str, ...]]]
 AxisRules = Dict[str, AxisName]
@@ -94,8 +93,8 @@ def use_rules(rules: AxisRules):
 def _mesh_axes(mesh: Optional[Mesh]) -> Tuple[str, ...]:
     if mesh is not None:
         return tuple(mesh.axis_names)
-    env = get_abstract_mesh()   # None on JAX < 0.5 (repro.compat)
-    if env is not None and env.axis_names:
+    env = jax.sharding.get_abstract_mesh()
+    if env.axis_names:
         return tuple(env.axis_names)
     return ()
 

@@ -44,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .bitmap_intersect import _popcount_sum
+from .spec import pair_spec, resolve_interpret, smem_out, smem_table
 
 
 def _kernel(n_blocks: int,
@@ -51,18 +52,18 @@ def _kernel(n_blocks: int,
             z_ref, cnt_ref, blocks_ref, alive_ref):
     """One candidate pair: blocked ES difference with zero-block skip.
 
-    minsup_ref: (1,) SMEM     — scalar-prefetch style threshold
-    u_ref/v_ref: (1, nb, bw)  VMEM operand rows
-    su_ref: (1, nb+1)         SMEM U suffix popcount row (mass source)
-    rho_ref: (1,) SMEM        — parent support (difference bound)
-    z_ref: (1, nb, bw) VMEM   — diffset row (zeros past abort)
-    cnt_ref, blocks_ref, alive_ref: (1,) SMEM outputs
+    minsup_ref: (1,) SMEM        — scalar threshold (whole array)
+    u_ref/v_ref: (1, nb, bw)     VMEM operand rows
+    su_ref: (1, 1, nb+1)         SMEM U suffix popcount row (mass source)
+    rho_ref: (1, 1, 1) SMEM      — parent support (difference bound)
+    z_ref: (1, nb, bw) VMEM      — diffset row (zeros past abort)
+    cnt_ref, blocks_ref, alive_ref: (1, 1, 1) SMEM outputs
     """
     minsup = minsup_ref[0]
-    rho = rho_ref[0]
+    rho = rho_ref[0, 0, 0]
 
     # Dead blocks must read back as zero: clear the output row first.
-    z_ref[0] = jnp.zeros_like(z_ref[0])
+    z_ref[...] = jnp.zeros_like(z_ref)
 
     def cond(carry):
         k, _, _, alive = carry
@@ -70,10 +71,10 @@ def _kernel(n_blocks: int,
 
     def body(carry):
         k, cnt, blocks, alive = carry
-        z_k = u_ref[0, k] & ~v_ref[0, k]
-        z_ref[0, k] = z_k
+        z_k = u_ref[0, pl.ds(k, 1), :] & ~v_ref[0, pl.ds(k, 1), :]
+        z_ref[0, pl.ds(k, 1), :] = z_k
         cnt = cnt + _popcount_sum(z_k)
-        mass = su_ref[0, k] - su_ref[0, k + 1]
+        mass = su_ref[0, 0, k] - su_ref[0, 0, k + 1]
         blocks = blocks + (mass > 0).astype(jnp.int32)
         alive = (rho - cnt) >= minsup
         return k + 1, cnt, blocks, alive
@@ -81,9 +82,9 @@ def _kernel(n_blocks: int,
     _, cnt, blocks, alive = jax.lax.while_loop(
         cond, body,
         (jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.bool_(True)))
-    cnt_ref[0] = cnt
-    blocks_ref[0] = blocks
-    alive_ref[0] = alive.astype(jnp.int32)
+    cnt_ref[0, 0, 0] = cnt
+    blocks_ref[0, 0, 0] = blocks
+    alive_ref[0, 0, 0] = alive.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -94,41 +95,42 @@ def bitmap_diff_es(
     rho_parent: jnp.ndarray,  # int32  (n_pairs,)
     minsup: jnp.ndarray,      # int32  scalar; <= 0 disables ES
     *,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pallas ES difference.  Returns (Z, counts, blocks_done, alive).
 
-    ``interpret=True`` (the CPU default here) runs the kernel body in the
-    Pallas interpreter for validation; on TPU pass ``interpret=False``.
+    ``interpret=None`` compiles for the TPU there and runs the Pallas
+    interpreter on the CPU (``ops._pallas_interpret``).
     """
+    interpret = resolve_interpret(interpret)
     n_pairs, n_blocks, bw = U.shape
     minsup_arr = jnp.reshape(jnp.asarray(minsup, jnp.int32), (1,))
+    smem = pltpu.SMEM
 
     kernel = functools.partial(_kernel, n_blocks)
     z, cnt, blocks, alive = pl.pallas_call(
         kernel,
         grid=(n_pairs,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # minsup (whole array)
-            pl.BlockSpec((1, n_blocks, bw), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, n_blocks, bw), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, n_blocks + 1), lambda p: (p, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=smem),  # minsup (whole array)
+            pair_spec((n_blocks, bw)),
+            pair_spec((n_blocks, bw)),
+            pair_spec((1, n_blocks + 1), smem),
+            pair_spec((1, 1), smem),
         ],
         out_specs=[
-            pl.BlockSpec((1, n_blocks, bw), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
+            pair_spec((n_blocks, bw)),
+            pair_spec((1, 1), smem),
+            pair_spec((1, 1), smem),
+            pair_spec((1, 1), smem),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pairs, n_blocks, bw), jnp.uint32),
-            jax.ShapeDtypeStruct((n_pairs,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pairs,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pairs,), jnp.int32),
+            smem_out(n_pairs),
+            smem_out(n_pairs),
+            smem_out(n_pairs),
         ],
         interpret=interpret,
-    )(minsup_arr, U, V, suffix_u.astype(jnp.int32),
-      rho_parent.astype(jnp.int32))
-    return z, cnt, blocks, alive.astype(jnp.bool_)
+    )(minsup_arr, U, V, smem_table(suffix_u), smem_table(rho_parent))
+    return (z, cnt[:, 0, 0], blocks[:, 0, 0],
+            alive[:, 0, 0].astype(jnp.bool_))

@@ -57,6 +57,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .spec import pair_spec, resolve_interpret, smem_out, smem_table
+
 
 def _popcount_sum(z: jnp.ndarray) -> jnp.ndarray:
     """SWAR popcount of a uint32 block, summed to a scalar int32."""
@@ -73,17 +75,21 @@ def _kernel(mode: str, n_blocks: int,
             z_ref, cnt_ref, blocks_ref):
     """One candidate pair: blocked ES intersection.
 
-    minsup_ref: (1,) SMEM     — scalar-prefetch style threshold
-    u_ref/v_ref: (1, nb, bw)  VMEM operand rows
-    su_ref/sv_ref: (1, nb+1)  SMEM suffix popcount rows
-    rho_ref: (1,) SMEM        — parent support (andnot mode)
-    z_ref: (1, nb, bw) VMEM   — intersection/diffset row (zeros past abort)
-    cnt_ref, blocks_ref: (1,) SMEM outputs
+    minsup_ref: (1,) SMEM        — scalar threshold (whole array)
+    u_ref/v_ref: (1, nb, bw)     VMEM operand rows
+    su_ref/sv_ref: (1, 1, nb+1)  SMEM suffix popcount rows
+    rho_ref: (1, 1, 1) SMEM      — parent support (andnot mode)
+    z_ref: (1, nb, bw) VMEM      — intersection/diffset row (zeros past abort)
+    cnt_ref, blocks_ref: (1, 1, 1) SMEM outputs
+
+    Per-pair tables carry a unit middle axis so every block's last two
+    dims equal the array's — the TPU lowering's tiling rule for blocks
+    that are not (8, 128)-divisible.
     """
     minsup = minsup_ref[0]
 
     # Dead blocks must read back as zero: clear the output row first.
-    z_ref[0] = jnp.zeros_like(z_ref[0])
+    z_ref[...] = jnp.zeros_like(z_ref)
 
     def cond(carry):
         k, _, alive = carry
@@ -91,22 +97,23 @@ def _kernel(mode: str, n_blocks: int,
 
     def body(carry):
         k, cnt, alive = carry
-        u_k = u_ref[0, k]
-        v_k = v_ref[0, k]
+        u_k = u_ref[0, pl.ds(k, 1), :]
+        v_k = v_ref[0, pl.ds(k, 1), :]
         z_k = u_k & (v_k if mode == "and" else ~v_k)
-        z_ref[0, k] = z_k
+        z_ref[0, pl.ds(k, 1), :] = z_k
         cnt = cnt + _popcount_sum(z_k)
         if mode == "and":
-            bound = cnt + jnp.minimum(su_ref[0, k + 1], sv_ref[0, k + 1])
+            bound = cnt + jnp.minimum(su_ref[0, 0, k + 1],
+                                      sv_ref[0, 0, k + 1])
         else:
-            bound = rho_ref[0] - cnt
+            bound = rho_ref[0, 0, 0] - cnt
         alive = bound >= minsup
         return k + 1, cnt, alive
 
     k_end, cnt, _ = jax.lax.while_loop(
         cond, body, (jnp.int32(0), jnp.int32(0), jnp.bool_(True)))
-    cnt_ref[0] = cnt
-    blocks_ref[0] = k_end
+    cnt_ref[0, 0, 0] = cnt
+    blocks_ref[0, 0, 0] = k_end
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "interpret"))
@@ -119,45 +126,46 @@ def bitmap_intersect_es(
     minsup: jnp.ndarray,      # int32  scalar; <= 0 disables ES
     *,
     mode: str = "and",
-    interpret: bool = True,
+    interpret: "bool | None" = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pallas ES intersection.  Returns (Z, counts, blocks_done, alive).
 
-    ``interpret=True`` (the CPU default here) runs the kernel body in the
-    Pallas interpreter for validation; on TPU pass ``interpret=False``.
+    ``interpret=None`` compiles for the TPU there and runs the Pallas
+    interpreter on the CPU (``ops._pallas_interpret``).
     """
+    interpret = resolve_interpret(interpret)
     if mode not in ("and", "andnot"):
         raise ValueError(f"bad mode {mode!r}")
     n_pairs, n_blocks, bw = U.shape
     minsup_arr = jnp.reshape(jnp.asarray(minsup, jnp.int32), (1,))
+    smem = pltpu.SMEM
 
     kernel = functools.partial(_kernel, mode, n_blocks)
     z, cnt, blocks = pl.pallas_call(
         kernel,
         grid=(n_pairs,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # minsup (whole array)
-            pl.BlockSpec((1, n_blocks, bw), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, n_blocks, bw), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, n_blocks + 1), lambda p: (p, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n_blocks + 1), lambda p: (p, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=smem),  # minsup (whole array)
+            pair_spec((n_blocks, bw)),
+            pair_spec((n_blocks, bw)),
+            pair_spec((1, n_blocks + 1), smem),
+            pair_spec((1, n_blocks + 1), smem),
+            pair_spec((1, 1), smem),
         ],
         out_specs=[
-            pl.BlockSpec((1, n_blocks, bw), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda p: (p,), memory_space=pltpu.SMEM),
+            pair_spec((n_blocks, bw)),
+            pair_spec((1, 1), smem),
+            pair_spec((1, 1), smem),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pairs, n_blocks, bw), jnp.uint32),
-            jax.ShapeDtypeStruct((n_pairs,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pairs,), jnp.int32),
+            smem_out(n_pairs),
+            smem_out(n_pairs),
         ],
         interpret=interpret,
-    )(minsup_arr, U, V, suffix_u.astype(jnp.int32),
-      suffix_v.astype(jnp.int32), rho_parent.astype(jnp.int32))
+    )(minsup_arr, U, V, smem_table(suffix_u), smem_table(suffix_v),
+      smem_table(rho_parent))
+    cnt, blocks = cnt[:, 0, 0], blocks[:, 0, 0]
     # Recover the ref's ``alive`` flag: a pair that processed every block is
     # alive iff its *final* bound clears minsup (the final "and" bound is
     # exactly ``cnt`` since the suffix table ends in 0); a pair that exited

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .spec import resolve_interpret
+
 DEFAULT_Q_BLOCK = 128
 DEFAULT_KV_BLOCK = 128
 NEG_INF = -1e30
@@ -97,8 +99,9 @@ def flash_attention(
     softmax_scale: Optional[float] = None,
     q_block: int = DEFAULT_Q_BLOCK,
     kv_block: int = DEFAULT_KV_BLOCK,
-    interpret: bool = True,
+    interpret: "bool | None" = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     B, Sq, H, D = q.shape
     _, Skv, KH, Dv = v.shape
     assert H % KH == 0

@@ -4,9 +4,14 @@
   * ``"jnp"``      — the pure-jnp reference path (kernels/ref.py).  This is
                      the production path on CPU hosts and the oracle for
                      kernel tests.
-  * ``"pallas"``   — the Pallas kernel; interpret mode is picked
-                     automatically when no TPU is attached.
+  * ``"pallas"``   — the Pallas kernel: compiled for the chip on TPU, run
+                     in the Pallas interpreter on CPU (tests), refused on
+                     any other platform (:func:`_pallas_interpret`).
   * ``"auto"``     — pallas on TPU, jnp elsewhere (default).
+
+:func:`_resolve` turns a requested backend into the one that
+runs: ``"jnp"``, ``"pallas"`` (compiled) or ``"interpret"``; the jitted
+bodies below take that resolved value as a static argument.
 
 All wrappers keep shapes static-friendly: callers pad pair batches to
 bucketed sizes (core/eclat.py::_bucket_pad) so jit caches stay small.
@@ -58,7 +63,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
+from repro.compat import shard_map
 from repro.core.bitmap import popcount32 as _popcount32
 from repro.core.bitmap import suffix_popcounts as _suffix_popcounts
 
@@ -71,12 +76,36 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _pallas_interpret() -> bool:
+    """Whether a Pallas kernel runs in the interpreter — the one place
+    this is decided.  False on the TPU (the kernel is compiled), True on
+    the CPU (tests and CPU hosts validate the kernel bodies there), and
+    an error on any other platform: the kernels are written for the TPU
+    and nothing else may silently interpret them."""
+    if _on_tpu():
+        return False
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for the TPU and are interpreted only on "
+        f"the CPU; this process runs on {platform!r}")
+
+
 def _resolve(backend: str) -> str:
+    """Requested backend -> the one that runs: ``"jnp"``, ``"pallas"``
+    (compiled for the TPU) or ``"interpret"`` (the Pallas interpreter,
+    CPU only)."""
     if backend == "auto":
-        return "pallas" if _on_tpu() else "jnp"
-    if backend not in ("jnp", "pallas"):
+        backend = "pallas" if _on_tpu() else "jnp"
+    if backend == "jnp":
+        return "jnp"
+    if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
-    return backend
+    return "interpret" if _pallas_interpret() else "pallas"
+
+
+_PALLAS = ("pallas", "interpret")
 
 
 def bitmap_intersect_es(U, V, suffix_u, suffix_v, rho_parent, minsup,
@@ -86,9 +115,9 @@ def bitmap_intersect_es(U, V, suffix_u, suffix_v, rho_parent, minsup,
     """Blocked early-stopping intersection.  See kernels/ref.py for the
     exact semantics.  Returns (Z, counts, blocks_done, alive)."""
     b = _resolve(backend)
-    if b == "pallas":
+    if b in _PALLAS:
         return _pallas_bitmap(U, V, suffix_u, suffix_v, rho_parent, minsup,
-                              mode=mode, interpret=not _on_tpu())
+                              mode=mode, interpret=b == "interpret")
     return _ref.bitmap_intersect_es_ref(U, V, suffix_u, suffix_v,
                                         rho_parent, minsup, mode=mode)
 
@@ -106,10 +135,10 @@ def _screen_and_intersect_impl(rows, suffix, ua, vb, slots, rho_parent,
     V = jnp.take(rows, vb, axis=0)
     su = jnp.take(suffix, ua, axis=0)
     sv = jnp.take(suffix, vb, axis=0)
-    if backend == "pallas":
+    if backend in _PALLAS:
         Z, cnt, blocks, alive = _pallas_bitmap(
             U, V, su, sv, rho_parent, es_minsup, mode=mode,
-            interpret=not _on_tpu())
+            interpret=backend == "interpret")
     else:
         Z, cnt, blocks, alive = _ref.bitmap_intersect_es_ref(
             U, V, su, sv, rho_parent, es_minsup, mode=mode)
@@ -165,9 +194,10 @@ def _screen_and_diff_impl(rows, suffix, ua, vb, slots, rho_parent,
     U = jnp.take(rows, ua, axis=0)
     V = jnp.take(rows, vb, axis=0)
     su = jnp.take(suffix, ua, axis=0)
-    if backend == "pallas":
+    if backend in _PALLAS:
         Z, cnt, blocks, alive = _pallas_diff(
-            U, V, su, rho_parent, es_minsup, interpret=not _on_tpu())
+            U, V, su, rho_parent, es_minsup,
+            interpret=backend == "interpret")
     else:
         Z, cnt, blocks, alive = _ref.bitmap_diff_es_ref(
             U, V, su, rho_parent, es_minsup)
@@ -378,11 +408,11 @@ def make_screen_and_intersect_sharded(mesh: Mesh,
         suffix = suffix.at[slots_eff].set(child_suffix, mode="drop")
         return rows, suffix, bound, count, blocks, alive_g
 
-    mapped = _shard_map(
+    mapped = shard_map(
         fused, mesh=mesh,
         in_specs=(rows_spec, suffix_spec, vec, vec, vec, vec, P(), P()),
         out_specs=(rows_spec, suffix_spec, vec, vec, vec, vec),
-        check_rep=False)
+        check_vma=False)
     jitted = jax.jit(mapped, donate_argnums=(0, 1))
 
     def dispatch(rows, suffix, ua, vb, slots, rho_parent, minsup,
@@ -410,6 +440,7 @@ def make_screen_and_intersect_sharded(mesh: Mesh,
                       jnp.asarray(minsup, jnp.int32),
                       jnp.asarray(n_real_blocks, jnp.int32))
 
+    dispatch.program = jitted       # the jitted program, for AOT lowering
     return dispatch
 
 
@@ -418,9 +449,9 @@ def make_screen_and_intersect_sharded(mesh: Mesh,
 # reused in place anyway.
 @functools.partial(jax.jit, static_argnames=("backend",))
 def _compact_rows_impl(rows, suffix, perm, *, backend):
-    if backend == "pallas":
+    if backend in _PALLAS:
         from .compact import compact_gather as _pg
-        interp = not _on_tpu()
+        interp = backend == "interpret"
         return (_pg(rows, perm, interpret=interp),
                 _pg(suffix, perm, interpret=interp))
     return (_ref.compact_gather_ref(rows, perm),
@@ -443,9 +474,9 @@ def compact_rows(rows, suffix, perm, *, backend: str = "auto",
 
 @functools.partial(jax.jit, static_argnames=("backend",))
 def _compact_codes_impl(codes, perm, *, backend):
-    if backend == "pallas":
+    if backend in _PALLAS:
         from .compact import compact_gather as _pg
-        return _pg(codes, perm, interpret=not _on_tpu())
+        return _pg(codes, perm, interpret=backend == "interpret")
     return _ref.compact_gather_ref(codes, perm)
 
 
@@ -471,13 +502,13 @@ def bitmap_count(U, V, *, backend: str = "auto") -> jnp.ndarray:
     # The jnp path is already a single fused AND+popcount+reduce; the
     # pallas path reuses the ES kernel with minsup=0 (never aborts).
     b = _resolve(backend)
-    if b == "pallas":
+    if b in _PALLAS:
         n_pairs, n_blocks, _ = U.shape
         zeros = jnp.zeros((n_pairs, n_blocks + 1), jnp.int32)
         rho = jnp.zeros((n_pairs,), jnp.int32)
         _, cnt, _, _ = _pallas_bitmap(U, V, zeros, zeros, rho,
                                       jnp.int32(0), mode="and",
-                                      interpret=not _on_tpu())
+                                      interpret=b == "interpret")
         return cnt
     return _ref.bitmap_count_ref(U, V)
 
@@ -494,10 +525,10 @@ def flash_attention(q, k, v, *, causal: bool = True, softmax_scale=None,
                     backend: str = "auto"):
     """Fused attention: Pallas kernel on TPU, dense ref elsewhere."""
     b = _resolve(backend)
-    if b == "pallas":
+    if b in _PALLAS:
         from .flash_attention import flash_attention as _fa
         return _fa(q, k, v, causal=causal, softmax_scale=softmax_scale,
-                   interpret=not _on_tpu())
+                   interpret=b == "interpret")
     return _ref.flash_attention_ref(q, k, v, causal=causal,
                                     softmax_scale=softmax_scale)
 
@@ -506,10 +537,10 @@ def embedding_bag(table, ids, mask, *, combiner: str = "mean",
                   backend: str = "auto"):
     """Fused EmbeddingBag: Pallas on TPU, take+reduce elsewhere."""
     b = _resolve(backend)
-    if b == "pallas":
+    if b in _PALLAS:
         from .segment_embed import embedding_bag as _eb
         return _eb(table, ids, mask, combiner=combiner,
-                   interpret=not _on_tpu())
+                   interpret=b == "interpret")
     return _ref.embedding_bag_ref(table, ids, mask, combiner=combiner)
 
 
@@ -521,12 +552,12 @@ def nlist_intersect(u_pre, u_post, u_freq, v_pre, v_post, v_freq,
     The mining hot path uses :func:`nlist_extend` — this standalone
     variant takes host-materialised padded batches."""
     b = _resolve(backend)
-    if b == "pallas":
+    if b in _PALLAS:
         from .nlist_merge import nlist_merge as _pallas_merge
         return _pallas_merge(u_pre, u_post, u_freq, v_pre, v_post, v_freq,
                              u_len, v_len, rho_v, minsup,
                              early_stop=early_stop,
-                             interpret=not _on_tpu())
+                             interpret=b == "interpret")
     return _ref.nlist_intersect_ref(u_pre, u_post, u_freq,
                                     v_pre, v_post, v_freq,
                                     u_len, v_len, rho_v, minsup,
@@ -538,12 +569,12 @@ def _nl_merge_backend(codes, u_off, u_len, v_off, v_len, rho_v, minsup,
     """Shared gather + two-pointer-merge body of the N-list dispatches."""
     u_pre, u_post, u_freq = _ref._nl_gather(codes, u_off, u_len, lu)
     v_pre, v_post, v_freq = _ref._nl_gather(codes, v_off, v_len, lv)
-    if backend == "pallas":
+    if backend in _PALLAS:
         from .nlist_merge import nlist_merge as _pallas_merge
         merged = _pallas_merge(
             u_pre, u_post, u_freq, v_pre, v_post, v_freq,
             u_len, v_len, rho_v, minsup, early_stop=early_stop,
-            interpret=not _on_tpu())
+            interpret=backend == "interpret")
     else:
         merged = _ref._nl_merge_vmapped(
             u_pre, u_post, u_freq, v_pre, v_post, v_freq,

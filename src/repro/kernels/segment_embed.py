@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .spec import resolve_interpret
+
 DEFAULT_BAG_BLOCK = 8
 
 
@@ -55,7 +57,8 @@ def embedding_bag(table: jnp.ndarray,        # (V, D)
                   mask: jnp.ndarray,         # (B, L) int32/bool
                   *, combiner: str = "mean",
                   bag_block: int = DEFAULT_BAG_BLOCK,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: "bool | None" = None) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     if combiner not in ("sum", "mean"):
         raise ValueError(combiner)
     B, L = ids.shape

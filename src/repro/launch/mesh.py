@@ -8,9 +8,8 @@ touches jax device state (the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import; tests and benches see the real single CPU device).
 
-Mesh construction goes through :mod:`repro.compat` — ``axis_types`` /
-``jax.sharding.AxisType`` only exist on JAX >= 0.5 and the supported
-floor is 0.4.30.
+Mesh construction goes through :func:`repro.compat.make_mesh` (Auto
+axis types on every axis).
 """
 
 from __future__ import annotations

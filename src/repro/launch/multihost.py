@@ -68,13 +68,13 @@ class Heartbeat:
 
 def _psum_one():
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     import numpy as np
     devs = np.array(jax.devices())
     mesh = jax.sharding.Mesh(devs, ("i",))
     f = shard_map(lambda x: jax.lax.psum(x, "i"), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)
+                  in_specs=P(), out_specs=P(), check_vma=False)
     return f(jnp.ones(()))
 
 

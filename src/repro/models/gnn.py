@@ -184,7 +184,7 @@ def make_sharded_loss(mesh, cfg: SAGEConfig, n_nodes: int, f_pad: int,
                       node_axes=("data",), feat_axis: str = "model"):
     import functools
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
 
     node_spec = node_axes if len(node_axes) > 1 else node_axes[0]
     h_dim = cfg.d_hidden
@@ -217,7 +217,7 @@ def make_sharded_loss(mesh, cfg: SAGEConfig, n_nodes: int, f_pad: int,
         shard_map, mesh=mesh,
         in_specs=(P(), P(node_spec, feat_axis), P(node_spec), P(node_spec),
                   P(node_spec), P(node_spec)),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     def loss_fn(params, x, edge_src, edge_dst_local, labels, mask):
         n_local = x.shape[0]
         deg = jax.ops.segment_sum(

@@ -3,8 +3,7 @@
 The multi-device pieces run in a subprocess with
 ``--xla_force_host_platform_device_count=8`` so the main pytest process
 keeps the real single-device view.  Mesh construction goes through
-``repro.compat`` (JAX-version shim — the supported floor 0.4.30 has
-neither ``jax.sharding.AxisType`` nor ``get_abstract_mesh``).
+``repro.compat.make_mesh``.
 """
 
 import random
@@ -14,7 +13,6 @@ import textwrap
 
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -58,26 +56,6 @@ def test_use_rules_is_scoped():
 def test_divisibility_report():
     mesh = _mesh11()
     assert divisibility_report((16, 16), P("data", "model"), mesh) == []
-
-
-def test_mesh_compat_shim(monkeypatch):
-    """The version shim must keep working on newer JAX where AxisType /
-    get_abstract_mesh exist: strip them and assert the fallbacks engage
-    (on JAX < 0.5 this exercises the one production path)."""
-    from repro import compat
-
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    monkeypatch.delattr(jax.sharding, "get_abstract_mesh", raising=False)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    assert tuple(mesh.axis_names) == ("data", "model")
-    assert compat.get_abstract_mesh() is None
-    # rules resolution (distributed/sharding.py) survives the absence
-    assert logical_spec(("batch",), mesh) == P("data")
-    assert logical_spec(("batch",), None) == P(None)
-    # oldest floor: no jax.make_mesh at all -> mesh_utils fallback
-    monkeypatch.delattr(jax, "make_mesh", raising=False)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    assert tuple(mesh.axis_names) == ("data", "model")
 
 
 def test_arch_rules_divisible_on_production_mesh():
@@ -482,7 +460,7 @@ def test_distributed_miner_multi_device():
 def test_compressed_psum_int8_single_axis():
     """compressed_psum under shard_map on a 1-device mesh is identity-ish."""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
     from repro.distributed.compression import compressed_psum_int8
 
     mesh = _mesh11()
