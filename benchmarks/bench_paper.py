@@ -242,10 +242,10 @@ def run_smoke(out_path: str = "BENCH_smoke.json") -> Dict:
                              inflight=2)
     report["pipeline"] = {
         "regime": "powerlaw", "pair_chunk": pipe_chunk,
-        "serial": {"device_occupancy": st_ser.device_occupancy,
+        "serial": {"ring_overlap_frac": st_ser.ring_overlap_frac,
                    "assemble_s": round(st_ser.assemble_s, 6),
                    "resolve_s": round(st_ser.resolve_s, 6)},
-        "pipelined": {"device_occupancy": st_pipe.device_occupancy,
+        "pipelined": {"ring_overlap_frac": st_pipe.ring_overlap_frac,
                       "assemble_s": round(st_pipe.assemble_s, 6),
                       "resolve_s": round(st_pipe.resolve_s, 6)},
     }
@@ -284,8 +284,8 @@ def run_smoke(out_path: str = "BENCH_smoke.json") -> Dict:
                           "on": st_pon.scatter_words},
     }
     report["autotune"] = auto
-    print(f"smoke pipeline: occupancy {st_ser.device_occupancy:.2f} -> "
-          f"{st_pipe.device_occupancy:.2f} @chunk={pipe_chunk}; "
+    print(f"smoke pipeline: occupancy {st_ser.ring_overlap_frac:.2f} -> "
+          f"{st_pipe.ring_overlap_frac:.2f} @chunk={pipe_chunk}; "
           f"autotune device_calls bitmap "
           f"{st_boff.device_calls}->{st_bon.device_calls}, prepost "
           f"{st_poff.device_calls}->{st_pon.device_calls} "
@@ -326,11 +326,11 @@ def run_smoke(out_path: str = "BENCH_smoke.json") -> Dict:
     # powerlaw regime (occupancy strictly above the serial baseline,
     # which is 0.0 by construction) ...
     pp = report["pipeline"]
-    assert (pp["pipelined"]["device_occupancy"]
-            > pp["serial"]["device_occupancy"]), (
+    assert (pp["pipelined"]["ring_overlap_frac"]
+            > pp["serial"]["ring_overlap_frac"]), (
         f"pipelining overlapped nothing: occupancy "
-        f"{pp['pipelined']['device_occupancy']} <= serial "
-        f"{pp['serial']['device_occupancy']}")
+        f"{pp['pipelined']['ring_overlap_frac']} <= serial "
+        f"{pp['serial']['ring_overlap_frac']}")
     # ... and per-bucket widths reduce device_calls at unchanged
     # per-pair work (word_ops / comparisons / scatter_words).
     at = report["autotune"]

@@ -56,8 +56,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core.bitmap import DEFAULT_BLOCK_WORDS, BitmapDB, popcount32
-from repro.core.guards import host_sync
 from repro.core.eclat import (BitmapMiner, DeviceMiningStats, _bucket_pad,
+                              _count_lanes, _read_dispatch,
                               ItemsetSupports)  # noqa: F401 (re-export)
 from repro.core.rowstore import DeviceRowStore
 from repro.kernels import ops
@@ -304,20 +304,14 @@ class DistributedMiner(BitmapMiner):
             _bucket_pad(rho, n), np.int32(self._minsup),
             np.int32(self._n_blocks))   # real (unpadded) block count
         self._stats.device_calls += 1
+        _count_lanes(self._stats, n)
         return bound, count, blocks, scan_alive
 
     def _dispatch_resolve(self, raw: Tuple, n: int,
                           ) -> Tuple[np.ndarray, np.ndarray]:
         """Blocking readback of one sharded dispatch + attribution."""
         stats = self._stats
-        bound, count, blocks, scan_alive = raw
-        # host-sync: the audited group-retirement readback (PR 7) — one
-        # deliberate d2h per retired sharded dispatch
-        with host_sync("group-retirement accounting readback"):
-            bound = np.asarray(bound[:n])
-            count = np.asarray(count[:n])
-            blocks = np.asarray(blocks[:n])
-            scan_alive = np.asarray(scan_alive[:n])
+        bound, count, blocks, scan_alive = _read_dispatch(stats, raw, n)
         # In-dispatch shard-local block ES (ISSUE 4): each shard walks its
         # local blocks against the conservative threshold
         # ``minsup - slack`` (slack = the screen mass every OTHER shard

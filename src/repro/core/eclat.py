@@ -76,6 +76,7 @@ from typing import Any, Dict, FrozenSet, Hashable, List, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.bitmap import (BITMAP_REF_ROW_WORDS, BitmapDB,
@@ -86,6 +87,7 @@ from repro.core.frontier import (Child, ClassNode, EngineAccounting,
                                  FrontierScheduler)
 from repro.core.guards import host_sync
 from repro.core.rowstore import DeviceRowStore
+from repro.core.trace import span
 from repro.kernels import ops
 
 ItemsetSupports = Dict[FrozenSet[Hashable], int]
@@ -159,6 +161,29 @@ class DeviceMiningStats(EngineAccounting):
 
 def _bucket_pad(arr: np.ndarray, n: int, fill=0) -> np.ndarray:
     return bucket_pad(arr, n, _PAIR_BUCKETS, fill)
+
+
+def _count_lanes(stats: EngineAccounting, n: int) -> None:
+    """Charge one dispatch of ``n`` real pairs at its bucketed width."""
+    width = next(b for b in _PAIR_BUCKETS if n <= b)
+    stats.pair_lanes += width
+    stats.pad_lanes += width - n
+
+
+def _read_dispatch(stats: EngineAccounting, raw: Tuple, n: int,
+                   ) -> List[np.ndarray]:
+    """Blocking readback of one launched dispatch's outputs, trimmed to
+    its ``n`` real pairs: first the wait for the device (``sched.wait``,
+    summed into ``stats.wait_s``), then the copies (``sched.readback``).
+    Waiting first adds no synchronisation — the copies block anyway."""
+    # host-sync: the audited group-retirement readback (PR 7) — one
+    # deliberate d2h per retired dispatch, deferred via the handle
+    with host_sync("group-retirement accounting readback"):
+        with span("sched.wait", acc=(stats, "wait_s")):
+            # host-sync: waits on the dispatch the copies below read
+            jax.block_until_ready(raw)
+        with span("sched.readback"):
+            return [np.asarray(a[:n]) for a in raw]
 
 
 class PendingPairResult:
@@ -276,6 +301,7 @@ class BitmapMiner:
         # ``metrics`` is kept for API compatibility and no longer selects
         # a separate (two-dispatch) fast path.
         self.metrics = metrics
+        self._jobs = 0           # sequence number of the next job's spans
 
     # Dispatch chunks are sliced in units of this many pairs so each
     # cls-shard's slice stays aligned; the 2-D DistributedMiner sets it
@@ -296,6 +322,13 @@ class BitmapMiner:
         list-of-lists detour that ``mine`` takes)."""
         if minsup < 1:
             raise ValueError("minsup must be an absolute count >= 1")
+        job = self._jobs
+        self._jobs += 1
+        with span("mine", job=job):
+            return self._mine_packed(bdb, minsup)
+
+    def _mine_packed(self, bdb: BitmapDB, minsup: int,
+                     ) -> Tuple[ItemsetSupports, DeviceMiningStats]:
         stats = DeviceMiningStats()
         t0 = time.perf_counter()
 
@@ -531,6 +564,7 @@ class BitmapMiner:
                     mode=mode, early_stop=self.early_stop,
                     backend=self.backend)
         self._stats.device_calls += 1
+        _count_lanes(self._stats, n)
         return cnt, blocks, alive
 
     def _dispatch_resolve(self, raw: Tuple, n: int,
@@ -541,13 +575,7 @@ class BitmapMiner:
         the raw kernel count (support for "and", diffset size for
         "diff") and ``alive`` marks pairs that survived ES."""
         stats = self._stats
-        cnt, blocks, alive = raw
-        # host-sync: the audited group-retirement readback (PR 7) — one
-        # deliberate d2h per retired dispatch, deferred via the handle
-        with host_sync("group-retirement accounting readback"):
-            cnt = np.asarray(cnt[:n])
-            blocks = np.asarray(blocks[:n])
-            alive = np.asarray(alive[:n])
+        cnt, blocks, alive = _read_dispatch(stats, raw, n)
         stats.word_ops += int(blocks.sum()) * self.block_words
         if self.early_stop:
             # Attribution: a dead pair that did at most one (charged)

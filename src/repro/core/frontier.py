@@ -88,13 +88,13 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass
-from time import perf_counter
 from typing import (Any, Deque, Dict, Hashable, List, NamedTuple,
                     Optional, Tuple)
 
 import numpy as np
 
 from repro.core.guards import device_purity_guard
+from repro.core.trace import span
 
 
 @dataclass
@@ -107,12 +107,22 @@ class EngineAccounting:
     recent compaction epoch (0.0 when compaction never fired).
 
     Pipeline telemetry (ISSUE 7): ``inflight_groups`` is the ring depth
-    the run was configured with; ``device_occupancy`` is the fraction of
-    drain groups dispatched while an earlier group was still in flight
-    (deterministic — derived from ring state at dispatch, not from
-    timing — so it is exactly 0.0 for a serial ``inflight=1`` run);
-    ``assemble_s`` / ``resolve_s`` split host time between group
-    assembly+dispatch and blocking retire-time readbacks."""
+    the run was configured with; ``ring_overlap_frac`` is the fraction
+    of drain groups dispatched while an earlier group was still in
+    flight (deterministic — derived from ring state at dispatch, not
+    from timing — so it is exactly 0.0 for a serial ``inflight=1`` run;
+    it says nothing about device time); ``assemble_s`` / ``resolve_s``
+    split host time between group assembly+dispatch and blocking
+    retire-time readbacks.
+
+    Host timers and counters behind the profiler spans
+    (``core.trace``): ``wait_s`` is the time the host blocked on
+    dispatch outputs (``sched.wait``, a part of ``resolve_s``);
+    ``retire_s`` is group retirement outside its resolves
+    (``sched.retire`` less its ``sched.resolve`` spans);
+    ``upload_bytes`` are the host bytes the allocator put on the
+    device; ``pair_lanes`` / ``pad_lanes`` are the bucketed widths of
+    the pair dispatches and the padding in them."""
 
     candidates: int = 0
     nodes: int = 0
@@ -133,9 +143,15 @@ class EngineAccounting:
     scatter_words: int = 0
     # Dispatch-pipeline telemetry (ISSUE 7).
     inflight_groups: int = 1
-    device_occupancy: float = 0.0
+    ring_overlap_frac: float = 0.0
     assemble_s: float = 0.0
     resolve_s: float = 0.0
+    # Span-backed telemetry (see the class docstring).
+    wait_s: float = 0.0
+    retire_s: float = 0.0
+    upload_bytes: int = 0
+    pair_lanes: int = 0
+    pad_lanes: int = 0
 
     @property
     def deaths(self) -> int:
@@ -153,13 +169,15 @@ class EngineAccounting:
         self.peak_device_words = int(
             getattr(alloc, "peak_device_words", 0))
         self.compaction_occupancy = alloc.last_compaction_occupancy
+        self.upload_bytes = alloc.upload_bytes
 
     def note_scheduler(self, sched: "FrontierScheduler") -> None:
         """Pull the pipeline counters from the scheduler that ran."""
         self.inflight_groups = sched.inflight
-        self.device_occupancy = sched.device_occupancy
+        self.ring_overlap_frac = sched.ring_overlap_frac
         self.assemble_s = sched.assemble_s
         self.resolve_s = sched.resolve_s
+        self.retire_s = sched.retire_s
 
     def accounting_dict(self) -> Dict[str, float]:
         return {
@@ -170,7 +188,7 @@ class EngineAccounting:
             "child_scatters": self.child_scatters,
             "scatter_words": self.scatter_words,
             "inflight_groups": self.inflight_groups,
-            "device_occupancy": round(self.device_occupancy, 4),
+            "ring_overlap_frac": round(self.ring_overlap_frac, 4),
             "assemble_s": round(self.assemble_s, 6),
             "resolve_s": round(self.resolve_s, 6),
         }
@@ -214,13 +232,16 @@ class _InflightGroup:
     ``parts`` holds ``(chunk_lo, handle_or_results)`` per chunk slice:
     a lazy handle while readbacks are deferred, or an already-resolved
     result list (``inflight=1``, or clients returning plain iterables).
+    ``gid`` is the group's dispatch sequence number (the ``group`` id of
+    its spans).
     """
 
-    __slots__ = ("drained", "meta", "parts", "total")
+    __slots__ = ("gid", "drained", "meta", "parts", "total")
 
-    def __init__(self, drained: List[ClassNode],
+    def __init__(self, gid: int, drained: List[ClassNode],
                  meta: List[Tuple[int, int, int]],
                  parts: List[Tuple[int, Any]], total: int):
+        self.gid = gid
         self.drained = drained
         self.meta = meta
         self.parts = parts
@@ -284,9 +305,10 @@ class FrontierScheduler:
         self.groups_overlapped = 0
         self.assemble_s = 0.0
         self.resolve_s = 0.0
+        self.retire_s = 0.0
 
     @property
-    def device_occupancy(self) -> float:
+    def ring_overlap_frac(self) -> float:
         """Fraction of drain groups dispatched while the ring was
         non-empty (exactly 0.0 for a serial ``inflight=1`` run)."""
         return self.groups_overlapped / max(self.groups_dispatched, 1)
@@ -373,70 +395,76 @@ class FrontierScheduler:
                 if mapping is not None:
                     self.remap(mapping, drained)
 
-                t0 = perf_counter()
+                gid = self.groups_dispatched
                 r0 = self.resolve_s
-                cols, meta = self._assemble(drained)
-                widths = None
-                widths_fn = getattr(self.client, "chunk_widths", None)
-                if widths_fn is not None:
-                    widths = widths_fn(cols)
-                parts: List[Tuple[int, Any]] = []
-                for lo, sl in self._chunk_slices(total, widths):
-                    chunk = {k: v[sl] for k, v in cols.items()}
-                    handle = self.client.evaluate_pairs(chunk)
-                    if self.inflight == 1:
-                        # Serial mode resolves chunk-by-chunk so dead
-                        # slots are freed before the next chunk
-                        # allocates — bit-for-bit the pre-pipeline
-                        # accounting (slot reuse order included).
-                        handle = self._resolve(handle)
-                    parts.append((lo, handle))
+                with span("sched.launch", acc=(self, "assemble_s"),
+                          group=gid):
+                    with span("sched.assemble"):
+                        cols, meta = self._assemble(drained)
+                    widths = None
+                    widths_fn = getattr(self.client, "chunk_widths", None)
+                    if widths_fn is not None:
+                        widths = widths_fn(cols)
+                    parts: List[Tuple[int, Any]] = []
+                    for chunk_id, (lo, sl) in enumerate(
+                            self._chunk_slices(total, widths)):
+                        chunk = {k: v[sl] for k, v in cols.items()}
+                        with span("sched.dispatch", chunk=chunk_id):
+                            handle = self.client.evaluate_pairs(chunk)
+                        if self.inflight == 1:
+                            # Serial mode resolves chunk-by-chunk so dead
+                            # slots are freed before the next chunk
+                            # allocates — bit-for-bit the pre-pipeline
+                            # accounting (slot reuse order included).
+                            handle = self._resolve(handle, chunk_id)
+                        parts.append((lo, handle))
                 # Assembly time excludes any resolve time accrued inside
                 # the loop (inflight=1 resolves inline).
-                self.assemble_s += ((perf_counter() - t0)
-                                    - (self.resolve_s - r0))
+                self.assemble_s -= self.resolve_s - r0
                 if ring:
                     self.groups_overlapped += 1
                 self.groups_dispatched += 1
-                ring.append(_InflightGroup(drained, meta, parts, total))
+                ring.append(_InflightGroup(gid, drained, meta, parts, total))
             if ring:
                 self._retire(ring.popleft())
 
-    def _resolve(self, handle) -> List[Tuple[int, int, int, Any]]:
+    def _resolve(self, handle, chunk: int) -> List[Tuple[int, int, int, Any]]:
         """Materialise one chunk's deferred result (blocking readbacks
         + stats attribution happen inside the client handle)."""
-        t0 = perf_counter()
-        if hasattr(handle, "resolve"):
-            out = list(handle.resolve())
-        else:
-            out = list(handle)
-        self.resolve_s += perf_counter() - t0
-        return out
+        with span("sched.resolve", acc=(self, "resolve_s"), chunk=chunk):
+            if hasattr(handle, "resolve"):
+                return list(handle.resolve())
+            return list(handle)
 
     def _retire(self, group: _InflightGroup) -> None:
         """Pop one group from the ring: resolve its deferred handles,
         emit survivors, push child classes in canonical order, release
-        the consumed operand rows."""
-        drained, meta = group.drained, group.meta
-        groups: Dict[Tuple[int, int], List[Tuple[int, Child]]] = {}
-        for lo, part in group.parts:
-            results = part if isinstance(part, list) else self._resolve(part)
-            for ki, row, support, extra in results:
-                ci, a, b = meta[lo + ki]
-                klass = drained[ci]
-                itemset = klass.itemsets[a] + (klass.itemsets[b][-1],)
-                self.client.emit(itemset, support)
-                groups.setdefault((ci, a), []).append(
-                    (b, Child(itemset, row, support, extra)))
-        # Child classes are rebuilt in canonical sibling order (b
-        # ascending), NOT evaluation order: chunk_sort_key may have
-        # permuted the pairs, and class member order is load-bearing
-        # (pair orientation / search order within the class).
-        for ci, _a in sorted(groups):
-            kids = [c for _b, c in sorted(groups[(ci, _a)])]
-            self.push(self.client.make_class(drained[ci], kids))
-        for klass in drained:
-            self.client.release(klass)
+        the consumed operand rows.  ``retire_s`` takes this minus the
+        resolves inside it."""
+        r0 = self.resolve_s
+        with span("sched.retire", acc=(self, "retire_s"), group=group.gid):
+            drained, meta = group.drained, group.meta
+            groups: Dict[Tuple[int, int], List[Tuple[int, Child]]] = {}
+            for chunk_id, (lo, part) in enumerate(group.parts):
+                results = (part if isinstance(part, list)
+                           else self._resolve(part, chunk_id))
+                for ki, row, support, extra in results:
+                    ci, a, b = meta[lo + ki]
+                    klass = drained[ci]
+                    itemset = klass.itemsets[a] + (klass.itemsets[b][-1],)
+                    self.client.emit(itemset, support)
+                    groups.setdefault((ci, a), []).append(
+                        (b, Child(itemset, row, support, extra)))
+            # Child classes are rebuilt in canonical sibling order (b
+            # ascending), NOT evaluation order: chunk_sort_key may have
+            # permuted the pairs, and class member order is load-bearing
+            # (pair orientation / search order within the class).
+            for ci, _a in sorted(groups):
+                kids = [c for _b, c in sorted(groups[(ci, _a)])]
+                self.push(self.client.make_class(drained[ci], kids))
+            for klass in drained:
+                self.client.release(klass)
+        self.retire_s -= self.resolve_s - r0
 
     def _chunk_slices(self, total: int,
                       widths: Optional[np.ndarray],

@@ -70,6 +70,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.guards import host_sync
+from repro.core.trace import span
 from repro.core.bitmap import (NL_LEN_BUCKETS, nl_pad_len, popcount32_np,
                                suffix_popcounts)
 
@@ -117,6 +118,11 @@ class DeviceRowStore:
     ``mesh``/``tid_axes``: when given, the block axis is sharded across
     the product of those mesh axes and both slabs live under
     ``NamedSharding``s (see module docstring for the suffix layout).
+
+    Construction is two spans: ``store.build`` (the zero-padded host
+    slab) and ``store.upload`` (its transfer and the suffix tables);
+    ``upload_bytes`` counts every host array the store puts on the
+    device.
     """
 
     def __init__(self, rows_np: np.ndarray, *, capacity: int = 0,
@@ -143,19 +149,24 @@ class DeviceRowStore:
             self._rows_sharding = NamedSharding(mesh, P(None, tid_spec, None))
             self._suffix_sharding = NamedSharding(mesh, P(None, tid_spec))
 
-        slab = np.zeros((cap, nb, bw), np.uint32)
-        slab[:n, :rows_np.shape[1]] = rows_np
+        with span("store.build"):
+            slab = np.zeros((cap, nb, bw), np.uint32)
+            slab[:n, :rows_np.shape[1]] = rows_np
+            host_suffix = (None if mesh is None
+                           else _local_suffix_tables(slab, self.n_shards))
         self.n_blocks = nb
         self.local_blocks = nb // self.n_shards
         self.block_words = bw
-        if mesh is None:
-            self.rows = jnp.asarray(slab)             # uint32 (cap, nb, bw)
-            self.suffix = suffix_popcounts(self.rows)  # int32 (cap, nb+1)
-        else:
-            self.rows = jax.device_put(slab, self._rows_sharding)
-            self.suffix = jax.device_put(
-                _local_suffix_tables(slab, self.n_shards),
-                self._suffix_sharding)
+        with span("store.upload"):
+            if mesh is None:
+                self.rows = jnp.asarray(slab)          # uint32 (cap, nb, bw)
+                self.suffix = suffix_popcounts(self.rows)  # (cap, nb+1)
+                self.upload_bytes = slab.nbytes
+            else:
+                self.rows = jax.device_put(slab, self._rows_sharding)
+                self.suffix = jax.device_put(host_suffix,
+                                             self._suffix_sharding)
+                self.upload_bytes = slab.nbytes + host_suffix.nbytes
         self._free: List[int] = list(range(cap - 1, n - 1, -1))
         self.grows = 0
         self.compactions = 0
@@ -203,25 +214,26 @@ class DeviceRowStore:
         self._free.extend(int(i) for i in ids)
 
     def _grow(self, need: int) -> None:
-        old = self.capacity
-        new = _round_capacity(max(2 * old, need))
-        rows = jnp.concatenate(
-            [self.rows,
-             jnp.zeros((new - old, self.n_blocks, self.block_words),
-                       jnp.uint32)])
-        suffix = jnp.concatenate(
-            [self.suffix,
-             jnp.zeros((new - old, self.suffix.shape[1]), jnp.int32)])
-        if self._rows_sharding is not None:
-            # Re-place explicitly: concat of a sharded slab with fresh
-            # zeros must stay block-sharded for the shard_map dispatch.
-            rows = jax.device_put(rows, self._rows_sharding)
-            suffix = jax.device_put(suffix, self._suffix_sharding)
-        self.rows = rows
-        self.suffix = suffix
-        self._free.extend(range(new - 1, old - 1, -1))
-        self.grows += 1
-        self.peak_capacity = max(self.peak_capacity, new)
+        with span("store.grow"):
+            old = self.capacity
+            new = _round_capacity(max(2 * old, need))
+            rows = jnp.concatenate(
+                [self.rows,
+                 jnp.zeros((new - old, self.n_blocks, self.block_words),
+                           jnp.uint32)])
+            suffix = jnp.concatenate(
+                [self.suffix,
+                 jnp.zeros((new - old, self.suffix.shape[1]), jnp.int32)])
+            if self._rows_sharding is not None:
+                # Re-place explicitly: concat of a sharded slab with fresh
+                # zeros must stay block-sharded for the shard_map dispatch.
+                rows = jax.device_put(rows, self._rows_sharding)
+                suffix = jax.device_put(suffix, self._suffix_sharding)
+            self.rows = rows
+            self.suffix = suffix
+            self._free.extend(range(new - 1, old - 1, -1))
+            self.grows += 1
+            self.peak_capacity = max(self.peak_capacity, new)
 
     def compact(self, *, reserve: int = 0, backend: str = "jnp",
                 ) -> np.ndarray:
@@ -249,30 +261,32 @@ class DeviceRowStore:
         """
         from repro.kernels import ops
 
-        old_cap = self.capacity
-        free_mask = np.zeros(old_cap, bool)
-        # host-sync: host-side free-list mask; no device value touched
-        free_mask[np.asarray(self._free, np.int64)] = True
-        live = np.nonzero(~free_mask)[0].astype(np.int32)
-        n_live = int(live.size)
-        new_cap = _round_capacity(max(n_live + reserve, 1))
+        with span("store.compact"):
+            old_cap = self.capacity
+            free_mask = np.zeros(old_cap, bool)
+            # host-sync: host-side free-list mask; no device value touched
+            free_mask[np.asarray(self._free, np.int64)] = True
+            live = np.nonzero(~free_mask)[0].astype(np.int32)
+            n_live = int(live.size)
+            new_cap = _round_capacity(max(n_live + reserve, 1))
 
-        perm = np.full(new_cap, -1, np.int32)       # dest slot -> src slot
-        perm[:n_live] = live
-        rows, suffix = ops.compact_rows(self.rows, self.suffix, perm,
-                                        backend=backend)
-        if self._rows_sharding is not None:
-            rows = jax.device_put(rows, self._rows_sharding)
-            suffix = jax.device_put(suffix, self._suffix_sharding)
-        self.rows = rows
-        self.suffix = suffix
-        self._free = list(range(new_cap - 1, n_live - 1, -1))
-        self.compactions += 1
-        self.last_compaction_occupancy = n_live / max(new_cap, 1)
+            perm = np.full(new_cap, -1, np.int32)   # dest slot -> src slot
+            perm[:n_live] = live
+            rows, suffix = ops.compact_rows(self.rows, self.suffix, perm,
+                                            backend=backend)
+            self.upload_bytes += perm.nbytes
+            if self._rows_sharding is not None:
+                rows = jax.device_put(rows, self._rows_sharding)
+                suffix = jax.device_put(suffix, self._suffix_sharding)
+            self.rows = rows
+            self.suffix = suffix
+            self._free = list(range(new_cap - 1, n_live - 1, -1))
+            self.compactions += 1
+            self.last_compaction_occupancy = n_live / max(new_cap, 1)
 
-        mapping = np.full(old_cap, -1, np.int32)
-        mapping[live] = np.arange(n_live, dtype=np.int32)
-        return mapping
+            mapping = np.full(old_cap, -1, np.int32)
+            mapping[live] = np.arange(n_live, dtype=np.int32)
+            return mapping
 
     def compact_if_sparse(self, occupancy_threshold: float, *,
                           reserve: int = 0, backend: str = "jnp",
@@ -313,6 +327,7 @@ class NListPool:
         self.codes = jnp.zeros((cap, 3), jnp.int32)
         self._free: Dict[int, List[int]] = {}   # bucket size -> extent offs
         self._bump = 0                          # slab high-water mark
+        self.upload_bytes = 0                   # host arrays put on device
         self.grows = 0
         self.compactions = 0
         self.last_compaction_occupancy = 0.0
@@ -434,6 +449,7 @@ class NListPool:
         vals = np.concatenate([np.asarray(a, np.int32).reshape(-1, 3)
                                for a in code_arrays])
         self.codes = self.codes.at[jnp.asarray(idx)].set(jnp.asarray(vals))
+        self.upload_bytes += idx.nbytes + vals.nbytes
 
     def read_row(self, row: int) -> np.ndarray:
         """Row contents as ``int32 (len, 3)`` — tests/debug only (the
@@ -494,6 +510,7 @@ class NListPool:
         if bump:
             perm[:bump] = np.concatenate(idx_parts)
         self.codes = ops.compact_codes(self.codes, perm, backend=backend)
+        self.upload_bytes += perm.nbytes
         for r, off, bucket in new_off:
             self._row_off[r] = off
             self._row_cap[r] = bucket

@@ -131,27 +131,39 @@ def bitmap_intersect_es(U, V, suffix_u, suffix_v, rho_parent, minsup,
 def _screen_and_intersect_impl(rows, suffix, ua, vb, slots, rho_parent,
                                minsup, es_minsup, *, mode: str,
                                backend: str):
-    U = jnp.take(rows, ua, axis=0)
-    V = jnp.take(rows, vb, axis=0)
-    su = jnp.take(suffix, ua, axis=0)
-    sv = jnp.take(suffix, vb, axis=0)
-    if backend in _PALLAS:
-        Z, cnt, blocks, alive = _pallas_bitmap(
-            U, V, su, sv, rho_parent, es_minsup, mode=mode,
-            interpret=backend == "interpret")
-    else:
-        Z, cnt, blocks, alive = _ref.bitmap_intersect_es_ref(
-            U, V, su, sv, rho_parent, es_minsup, mode=mode)
+    with jax.named_scope("dispatch.gather"):
+        U = jnp.take(rows, ua, axis=0)
+        V = jnp.take(rows, vb, axis=0)
+        su = jnp.take(suffix, ua, axis=0)
+        sv = jnp.take(suffix, vb, axis=0)
+    with jax.named_scope("dispatch.kernel"):
+        if backend in _PALLAS:
+            Z, cnt, blocks, alive = _pallas_bitmap(
+                U, V, su, sv, rho_parent, es_minsup, mode=mode,
+                interpret=backend == "interpret")
+        else:
+            Z, cnt, blocks, alive = _ref.bitmap_intersect_es_ref(
+                U, V, su, sv, rho_parent, es_minsup, mode=mode)
     # Survivor-only scatter (ISSUE 5): the count phase above completes
     # before the scatter phase, and gates it — non-survivors' slots are
     # redirected out of range so ``mode="drop"`` discards their writes
     # together with the pair padding.
+    with jax.named_scope("dispatch.scatter"):
+        rows, suffix = _survivor_scatter(rows, suffix, Z, cnt, alive, slots,
+                                         rho_parent, minsup, mode=mode)
+    return rows, suffix, cnt, blocks, alive
+
+
+def _survivor_scatter(rows, suffix, Z, cnt, alive, slots, rho_parent,
+                      minsup, *, mode: str):
+    """Write surviving children and their suffix tables into the slab;
+    the other slots are redirected out of range and dropped."""
     keep = _ref._survivor_mask(cnt, alive, rho_parent, minsup, mode=mode)
     slots_eff = jnp.where(keep, slots, jnp.int32(rows.shape[0]))
     child_suffix = _suffix_popcounts(Z)
     rows = rows.at[slots_eff].set(Z, mode="drop")
     suffix = suffix.at[slots_eff].set(child_suffix, mode="drop")
-    return rows, suffix, cnt, blocks, alive
+    return rows, suffix
 
 
 def screen_and_intersect(rows, suffix, ua, vb, slots, rho_parent, minsup,
@@ -191,22 +203,21 @@ def screen_and_intersect(rows, suffix, ua, vb, slots, rho_parent, minsup,
                    donate_argnums=(0, 1))
 def _screen_and_diff_impl(rows, suffix, ua, vb, slots, rho_parent,
                           minsup, es_minsup, *, backend: str):
-    U = jnp.take(rows, ua, axis=0)
-    V = jnp.take(rows, vb, axis=0)
-    su = jnp.take(suffix, ua, axis=0)
-    if backend in _PALLAS:
-        Z, cnt, blocks, alive = _pallas_diff(
-            U, V, su, rho_parent, es_minsup,
-            interpret=backend == "interpret")
-    else:
-        Z, cnt, blocks, alive = _ref.bitmap_diff_es_ref(
-            U, V, su, rho_parent, es_minsup)
-    keep = _ref._survivor_mask(cnt, alive, rho_parent, minsup,
-                               mode="andnot")
-    slots_eff = jnp.where(keep, slots, jnp.int32(rows.shape[0]))
-    child_suffix = _suffix_popcounts(Z)
-    rows = rows.at[slots_eff].set(Z, mode="drop")
-    suffix = suffix.at[slots_eff].set(child_suffix, mode="drop")
+    with jax.named_scope("dispatch.gather"):
+        U = jnp.take(rows, ua, axis=0)
+        V = jnp.take(rows, vb, axis=0)
+        su = jnp.take(suffix, ua, axis=0)
+    with jax.named_scope("dispatch.kernel"):
+        if backend in _PALLAS:
+            Z, cnt, blocks, alive = _pallas_diff(
+                U, V, su, rho_parent, es_minsup,
+                interpret=backend == "interpret")
+        else:
+            Z, cnt, blocks, alive = _ref.bitmap_diff_es_ref(
+                U, V, su, rho_parent, es_minsup)
+    with jax.named_scope("dispatch.scatter"):
+        rows, suffix = _survivor_scatter(rows, suffix, Z, cnt, alive, slots,
+                                         rho_parent, minsup, mode="andnot")
     return rows, suffix, cnt, blocks, alive
 
 

@@ -65,8 +65,8 @@ def test_pipelined_matches_serial_bitmap(scheme):
         assert out1 == expected and out3 == expected, (scheme, seed)
         for f in _BITMAP_INVARIANT:
             assert getattr(st1, f) == getattr(st3, f), (scheme, seed, f)
-        assert st1.device_occupancy == 0.0
-        assert st3.device_occupancy > 0.0
+        assert st1.ring_overlap_frac == 0.0
+        assert st3.ring_overlap_frac > 0.0
         assert st1.inflight_groups == 1 and st3.inflight_groups == 3
 
 
@@ -80,8 +80,8 @@ def test_pipelined_matches_serial_prepost():
         assert out1 == expected and out3 == expected, seed
         for f in _NLIST_INVARIANT:
             assert getattr(st1, f) == getattr(st3, f), (seed, f)
-        assert st1.device_occupancy == 0.0
-        assert st3.device_occupancy > 0.0
+        assert st1.ring_overlap_frac == 0.0
+        assert st3.ring_overlap_frac > 0.0
 
 
 def test_pipelined_traversal_is_deterministic():
@@ -148,7 +148,7 @@ def test_leaf_only_drain_groups_terminate_cleanly():
     assert client.evaluated == 0
     assert len(client.released) == 6
     assert sched.groups_dispatched == 0
-    assert sched.device_occupancy == 0.0
+    assert sched.ring_overlap_frac == 0.0
 
 
 def test_leaf_groups_interleaved_with_real_groups():
@@ -159,7 +159,7 @@ def test_leaf_groups_interleaved_with_real_groups():
     ms = 3
     out, st = mine_bitmap(db, ms, block_words=1, pair_chunk=4, inflight=3)
     assert out == mine_bruteforce(db, ms)
-    assert st.device_occupancy > 0.0
+    assert st.ring_overlap_frac > 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -361,9 +361,9 @@ def test_occupancy_zero_iff_serial():
     ms = 4
     _, st1 = mine_bitmap(db, ms, block_words=1, pair_chunk=8, inflight=1)
     _, st2 = mine_bitmap(db, ms, block_words=1, pair_chunk=8, inflight=2)
-    assert st1.device_occupancy == 0.0
-    assert 0.0 < st2.device_occupancy <= 1.0
+    assert st1.ring_overlap_frac == 0.0
+    assert 0.0 < st2.ring_overlap_frac <= 1.0
     d = st2.as_dict()
     assert d["inflight_groups"] == 2
-    assert d["device_occupancy"] == round(st2.device_occupancy, 4)
+    assert d["ring_overlap_frac"] == round(st2.ring_overlap_frac, 4)
     assert "assemble_s" in d and "resolve_s" in d
