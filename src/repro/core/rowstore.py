@@ -61,6 +61,7 @@ re-bucketing as a defragmentation detail (level-1 uploads and
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -112,6 +113,23 @@ def _local_suffix_tables(rows_np: np.ndarray, n_shards: int) -> np.ndarray:
     return suf.reshape(n, n_shards * (nbl + 1))
 
 
+@functools.partial(jax.jit, static_argnames="cap")
+def _padded_slab(rows, suffix, *, cap: int):
+    """The store's initial slab and suffix table from its ``n`` real
+    rows, both zero-padded to ``cap`` rows on the device.
+
+    ``suffix`` is the real rows' per-shard suffix tables, or ``None`` for
+    the global table computed here from the real rows (a zero row's
+    suffix is all zeros, so padding the table equals computing it over
+    the whole slab).  The output slab is the program's only slab-sized
+    buffer."""
+    if suffix is None:
+        suffix = suffix_popcounts(rows)
+    pad = cap - rows.shape[0]
+    return (jnp.pad(rows, ((0, pad), (0, 0), (0, 0))),
+            jnp.pad(suffix, ((0, pad), (0, 0))))
+
+
 class DeviceRowStore:
     """Slab of bitmap rows + suffix tables resident on device.
 
@@ -119,10 +137,13 @@ class DeviceRowStore:
     the product of those mesh axes and both slabs live under
     ``NamedSharding``s (see module docstring for the suffix layout).
 
-    Construction is two spans: ``store.build`` (the zero-padded host
-    slab) and ``store.upload`` (its transfer and the suffix tables);
-    ``upload_bytes`` counts every host array the store puts on the
-    device.
+    Construction is two spans: ``store.build`` stages the ``n`` real
+    rows on the host (block axis padded to the shard count, and their
+    per-shard suffix tables, under a mesh) and ``store.upload`` covers
+    their transfer and :func:`_padded_slab`, which zero-pads them to the
+    ``capacity``-row slab on the device.  ``upload_bytes`` counts every
+    host array the store puts on the device: the real rows, not the
+    slab.
     """
 
     def __init__(self, rows_np: np.ndarray, *, capacity: int = 0,
@@ -150,23 +171,33 @@ class DeviceRowStore:
             self._suffix_sharding = NamedSharding(mesh, P(None, tid_spec))
 
         with span("store.build"):
-            slab = np.zeros((cap, nb, bw), np.uint32)
-            slab[:n, :rows_np.shape[1]] = rows_np
+            staged = rows_np
+            if nb > rows_np.shape[1]:
+                staged = np.pad(rows_np,
+                                ((0, 0), (0, nb - rows_np.shape[1]), (0, 0)))
             host_suffix = (None if mesh is None
-                           else _local_suffix_tables(slab, self.n_shards))
+                           else _local_suffix_tables(staged, self.n_shards))
         self.n_blocks = nb
         self.local_blocks = nb // self.n_shards
         self.block_words = bw
         with span("store.upload"):
-            if mesh is None:
-                self.rows = jnp.asarray(slab)          # uint32 (cap, nb, bw)
-                self.suffix = suffix_popcounts(self.rows)  # (cap, nb+1)
-                self.upload_bytes = slab.nbytes
-            else:
-                self.rows = jax.device_put(slab, self._rows_sharding)
-                self.suffix = jax.device_put(host_suffix,
-                                             self._suffix_sharding)
-                self.upload_bytes = slab.nbytes + host_suffix.nbytes
+            # The staged device arrays are the call's arguments only, so
+            # they are freed once the slab is built.
+            rows, suffix = _padded_slab(
+                jax.device_put(staged, self._rows_sharding),
+                None if mesh is None
+                else jax.device_put(host_suffix, self._suffix_sharding),
+                cap=cap)
+            if mesh is not None:
+                # Re-place as ``_grow`` does: the pad keeps the inputs'
+                # block sharding, so this only restores the store's
+                # sharding objects (a one-device mesh normalises them).
+                rows = jax.device_put(rows, self._rows_sharding)
+                suffix = jax.device_put(suffix, self._suffix_sharding)
+            self.rows = rows                   # uint32 (cap, nb, bw)
+            self.suffix = suffix
+            self.upload_bytes = staged.nbytes + (
+                0 if mesh is None else host_suffix.nbytes)
         self._free: List[int] = list(range(cap - 1, n - 1, -1))
         self.grows = 0
         self.compactions = 0

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import make_mesh
 from repro.core.bitmap import PAIR_CHUNK_BUCKETS, hbm_pair_cap
+from repro.core.rowstore import _padded_slab
 from repro.kernels import ops
 from repro.kernels.bitmap_diff import bitmap_diff_es
 from repro.kernels.bitmap_intersect import bitmap_intersect_es
@@ -149,6 +150,18 @@ def test_fused_dispatch_fits_hbm_at_the_capped_width(chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             < V5E_HBM), mem
+
+
+def test_store_builder_holds_one_slab(chip):
+    """The row store's builder pads kosarak-paper's 154 real rows to the
+    8192-row slab on the chip: the slab is its output and nothing
+    slab-sized is a temporary."""
+    compiled = _padded_slab.lower(chip((154, NB, BW), U32), None,
+                                  cap=SLAB).compile()
+    mem = compiled.memory_analysis()
+    slab_bytes = SLAB * NB * BW * 4
+    assert mem.output_size_in_bytes >= slab_bytes, mem
+    assert mem.temp_size_in_bytes < slab_bytes // 100, mem
 
 
 def test_sharded_dispatch_on_2x2_mesh_has_its_collectives(topo):

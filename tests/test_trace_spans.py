@@ -187,26 +187,36 @@ def test_scheduler_timers_keep_their_definitions(monkeypatch, inflight):
 # counters
 # ---------------------------------------------------------------------------
 
-def test_lane_and_upload_counters(monkeypatch):
-    widths, slabs = [], []
-    launch = eclat_mod.BitmapMiner._dispatch_launch
+def _store_spy(monkeypatch):
+    """Record ``(real row bytes, slab bytes)`` of every store built."""
+    built = []
     init = DeviceRowStore.__init__
+
+    def init_spy(self, rows_np, *a, **kw):
+        init(self, rows_np, *a, **kw)
+        built.append((rows_np.nbytes, self.rows.nbytes))
+
+    monkeypatch.setattr(DeviceRowStore, "__init__", init_spy)
+    return built
+
+
+def test_lane_and_upload_counters(monkeypatch):
+    widths = []
+    launch = eclat_mod.BitmapMiner._dispatch_launch
 
     def launch_spy(self, store, ua, *a, **kw):
         widths.append(int(ua.size))
         return launch(self, store, ua, *a, **kw)
 
-    def init_spy(self, *a, **kw):
-        init(self, *a, **kw)
-        slabs.append(self.rows.nbytes)
-
     monkeypatch.setattr(eclat_mod.BitmapMiner, "_dispatch_launch",
                         launch_spy)
-    monkeypatch.setattr(DeviceRowStore, "__init__", init_spy)
+    built = _store_spy(monkeypatch)
     out, st = BitmapMiner(block_words=1, pair_chunk=8,
                           compact_occupancy=0.0).mine(_db(1), 4)
-    assert st.compactions == 0 and len(slabs) == 1
-    assert st.upload_bytes == slabs[0]
+    assert st.compactions == 0 and len(built) == 1
+    # Only the real rows cross to the device; the slab is padded there.
+    (real, slab), = built
+    assert st.upload_bytes == real < slab
     buckets = [next(b for b in PAIR_CHUNK_BUCKETS if n <= b) for n in widths]
     assert st.pair_lanes == sum(buckets)
     assert st.pad_lanes == sum(b - n for b, n in
@@ -217,21 +227,18 @@ def test_lane_and_upload_counters(monkeypatch):
 
 
 def test_compaction_uploads_its_permutation(monkeypatch):
-    perms, slabs = [], []
+    perms = []
     compact = ops.compact_rows
-    init = DeviceRowStore.__init__
 
     def compact_spy(rows, suffix, perm, **kw):
         perms.append(perm.nbytes)
         return compact(rows, suffix, perm, **kw)
 
-    def init_spy(self, *a, **kw):
-        init(self, *a, **kw)
-        slabs.append(self.rows.nbytes)
-
     monkeypatch.setattr(ops, "compact_rows", compact_spy)
-    monkeypatch.setattr(DeviceRowStore, "__init__", init_spy)
+    built = _store_spy(monkeypatch)
     _, st = BitmapMiner(block_words=1, pair_chunk=8,
                         compact_occupancy=1.0).mine(_db(), 4)
     assert st.compactions == len(perms) > 0
-    assert st.upload_bytes == slabs[0] + sum(perms)
+    (real, slab), = built
+    assert st.upload_bytes == real + sum(perms)
+    assert real < slab
