@@ -125,6 +125,27 @@ def hbm_pair_cap(words_per_pair: int, memory_bytes: int,
     return fits[-1] if fits else bucket_table[0]
 
 
+# Device words an N-list dispatch keeps live per pair, per code of its
+# gather width, at lu = lv: the gathered U and V codes, the match table
+# and the Z-merge rows.  The TPU compiler measures 12.0 for the merge
+# pre-pass (temporaries and outputs) and 7.0 for the scatter pass, at
+# 4096 pairs of 32768 codes and 8192 pairs of 8192.
+NL_DISPATCH_WORDS_PER_CODE = 12
+
+
+def nl_hbm_pair_cap(bucket: int, memory_bytes: int) -> int:
+    """Widest bucketed N-list pair chunk whose dispatch temporaries at
+    gather widths ``lu = lv = bucket`` fit in half of ``memory_bytes``
+    (the other half holds the pool and the in-flight match tables).
+    Never below the smallest bucket.  On a 15.75 GiB v5e this is 4096
+    pairs at the 32768-code bucket, where an 8192-pair chunk needs
+    12.9 GB, and the default 8192 at 8192 codes and below."""
+    per_pair = NL_DISPATCH_WORDS_PER_CODE * 4 * max(1, int(bucket))
+    fits = [b for b in NL_PAIR_CHUNK_BUCKETS
+            if b * per_pair <= memory_bytes // 2]
+    return fits[-1] if fits else NL_PAIR_CHUNK_BUCKETS[0]
+
+
 def nl_pad_len(n: int) -> int:
     """Smallest N-list bucket >= ``n`` (power-of-two fallback past the
     largest tuned bucket)."""

@@ -1,8 +1,10 @@
 """Device-resident PrePost+: N-lists live in a pooled device slab.
 
-The PPC-tree build is inherently sequential host preprocessing (one pass
-over the reordered transactions — same category as tokenisation) and is
-shared with the oracle (``oracle.PPCTree``).  Everything after it is
+A job takes a packed ``BitmapDB`` (:meth:`DevicePrePost.mine_packed`).
+The PPC-tree's level-1 N-lists are built from the packed rows in numpy
+(:func:`build_nlists`: two sorts of the transactions' item paths, no
+loop over transactions), code for code the tree ``oracle.PPCTree``
+builds, which stays the plain reference.  Everything after it is
 device-resident (the ISSUE 3 unification — third engine on the shared
 allocator): every N-list the DFS can still touch is an extent of one
 persistent ``int32[capacity, 3]`` PPC-code slab
@@ -44,18 +46,21 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, FrozenSet, Hashable, List, Sequence, Tuple
+from typing import (Any, Dict, FrozenSet, Hashable, List, NamedTuple,
+                    Sequence, Tuple)
 
 import numpy as np
 
 from repro.core.guards import host_sync
-from repro.core.oracle import PPCTree, MiningStats
+from repro.core.oracle import MiningStats
 from repro.core.frontier import (Child, ClassNode, EngineAccounting,
                                  FrontierScheduler)
 from repro.core.rowstore import NListPool
 from repro.core.bitmap import (NL_LEN_BUCKETS, NL_PAIR_CHUNK_BUCKETS,
-                               NL_REF_LEN, bucket_pad, chunk_width_for,
-                               nl_pad_len, nl_pad_len_np)
+                               NL_REF_LEN, BitmapDB, bucket_pad,
+                               chunk_width_for, device_memory_bytes,
+                               nl_hbm_pair_cap, nl_pad_len, nl_pad_len_np)
+from repro.core.trace import span
 from repro.kernels import ops
 
 ItemsetSupports = Dict[FrozenSet[Hashable], int]
@@ -69,6 +74,116 @@ def _pad_len(n: int) -> int:
     """Bucketed N-list gather width (power-of-two fallback past the
     largest tuned bucket — huge N-lists must not be a hard error)."""
     return nl_pad_len(n)
+
+
+class PPCLists(NamedTuple):
+    """Level-1 N-lists of a PPC-tree, items in decreasing support
+    (``oracle.PPCTree.order_desc``): item ``k``'s codes are
+    ``codes[start_k : start_k + lengths[k]]``, ascending pre-order rank."""
+
+    items: List[Hashable]
+    supports: np.ndarray      # int64 (F,)
+    lengths: np.ndarray       # int64 (F,)
+    codes: np.ndarray         # int32 (sum(lengths), 3): pre, post, freq
+
+
+def _lex_order(mat: np.ndarray) -> np.ndarray:
+    """Stable order of ``mat``'s rows, lexicographic on their values
+    (unsigned, zero-padded on the right, so a prefix sorts first): each
+    row read as one big-endian byte string."""
+    be = np.ascontiguousarray(mat.astype(mat.dtype.newbyteorder(">")))
+    return np.argsort(be.view(f"S{be.shape[1] * be.itemsize}")[:, 0],
+                      kind="stable")
+
+
+def _lcp(paths: np.ndarray, length: np.ndarray) -> np.ndarray:
+    """Common prefix length of each row with the row before it (0 for
+    the first row), of zero-padded rows."""
+    diff = paths[1:] != paths[:-1]
+    lcp = np.zeros(len(paths), np.int64)
+    lcp[1:] = np.where(diff.any(axis=1), diff.argmax(axis=1),
+                       paths.shape[1])
+    return np.minimum(lcp, length)
+
+
+def build_nlists(bdb: BitmapDB, minsup: int) -> PPCLists:
+    """The PPC-tree of ``bdb``'s rows frequent at ``minsup``, as N-lists,
+    built from the packed bits with no loop over transactions.
+
+    A transaction's path is its frequent items in decreasing support,
+    ties broken by ``repr`` (the oracle's order); a tree node is a
+    distinct path prefix.  With the paths sorted so that every node's
+    transactions are neighbours, a row opens the nodes at the depths
+    past its common prefix with the row before it.  The oracle's tree
+    visits children in the order transactions introduced them, so the
+    rows are sorted twice: by item, which makes each node a run of rows
+    whose first transaction is the run's smallest id, then by those
+    first ids, depth by depth.  In that order the nodes, taken row by
+    row and depth by depth, come in pre-order, and a node's run and the
+    nodes the run opens give its frequency and subtree size:
+    ``post = pre - depth + size - 1`` (the root is pre 0, depth 0)."""
+    keep = np.flatnonzero(bdb.supports >= minsup)
+    order = sorted(keep.tolist(), key=lambda r: (-int(bdb.supports[r]),
+                                                 repr(bdb.items[r])))
+    items = [bdb.items[r] for r in order]
+    # host-sync: pack-time host supports; no device value touched
+    supports = np.asarray(bdb.supports, np.int64)[order]
+    n_items = len(order)
+    if n_items == 0:
+        return PPCLists(items, supports, np.zeros(0, np.int64),
+                        np.zeros((0, 3), np.int32))
+
+    # Each transaction's ranks + 1, ascending, zero-padded to one width.
+    words = np.ascontiguousarray(bdb.bitmaps[order]).reshape(n_items, -1)
+    bits = np.unpackbits(words.view(np.uint8), axis=1,
+                         bitorder="little")[:, :bdb.n_trans]
+    tid, rank = np.nonzero(np.ascontiguousarray(bits.T))
+    length = np.bincount(tid, minlength=bdb.n_trans)
+    tids = np.flatnonzero(length)
+    length = length[tids]
+    width = int(length.max())
+    paths = np.zeros((tids.size, width), np.min_scalar_type(n_items))
+    starts = np.cumsum(length) - length
+    paths.reshape(-1)[np.arange(tid.size)
+                      + np.repeat(np.arange(tids.size) * width - starts,
+                                  length)] = rank + 1
+
+    # Sorted by item, each node is a run of rows: per row and depth, the
+    # first transaction and the frequency of the row's node there.
+    by_item = _lex_order(paths)
+    paths, length, tids = paths[by_item], length[by_item], tids[by_item]
+    lcp = _lcp(paths, length)
+    first = np.zeros((width, tids.size), np.uint32)   # filled by depth
+    freq = np.zeros((width, tids.size), np.int32)
+    for d in range(width):
+        runs = np.flatnonzero(lcp <= d)
+        count = np.diff(np.r_[runs, tids.size])
+        first[d] = np.repeat(np.minimum.reduceat(tids, runs) + 1, count)
+        freq[d] = np.repeat(count, count)
+    first[np.arange(width)[:, None] >= length[None, :]] = 0
+    first, freq = first.T, freq.T
+
+    # Sorted by first transaction: pre-order.  Row i opens the nodes at
+    # depths lcp[i] + 1 .. length[i]; a node's run starts at the row
+    # that opens it.
+    by_first = _lex_order(first)
+    paths, length, freq = paths[by_first], length[by_first], freq[by_first]
+    lcp = _lcp(paths, length)
+    opened = length - lcp
+    row = np.repeat(np.arange(paths.shape[0]), opened)
+    cum = np.concatenate([[0], np.cumsum(opened)])
+    depth = lcp[row] + 1 + np.arange(row.size) - cum[row]
+    at = row * width + depth - 1
+    pre = 1 + np.arange(row.size)
+    count = freq.ravel()[at]
+    size = length[row] - depth + 1 + cum[row + count] - cum[row + 1]
+    post = pre - depth + size - 1
+
+    item = paths.ravel()[at] - 1                   # narrow: a radix sort
+    ids = np.argsort(item, kind="stable")          # pre ascending per item
+    codes = np.stack([pre[ids], post[ids], count[ids]], axis=1)
+    return PPCLists(items, supports, np.bincount(item, minlength=n_items),
+                    codes.astype(np.int32))
 
 
 class PendingMergeResult:
@@ -167,7 +282,17 @@ class PendingMergeResult:
 @dataclass
 class DevicePrePostStats(MiningStats, EngineAccounting):
     """Oracle-compatible counters plus the shared device-engine
-    accounting struct (``frontier.EngineAccounting``)."""
+    accounting struct (``frontier.EngineAccounting``).
+
+    ``build_s`` is the host time of the ``ppc.build`` span (the PPC-tree
+    build and the level-1 N-lists' upload).  ``nl_codes`` counts the real
+    operand codes the merge pre-passes gathered (``|U| + |V|`` per
+    candidate); ``nl_gather_codes`` counts what they gathered at the
+    padded widths (padded pairs x (``lu`` + ``lv``))."""
+
+    build_s: float = 0.0
+    nl_codes: int = 0
+    nl_gather_codes: int = 0
 
     # Legacy names kept as read-only views of the shared accounting.
     @property
@@ -208,6 +333,7 @@ class DevicePrePost:
     def __init__(self, early_stop: bool = True, pair_chunk: int = 8192,
                  backend: str = "auto", compact_occupancy: float = 0.25,
                  inflight: int = 2, autotune_chunk: bool = False):
+        self._jobs = 0           # sequence number of the next job's spans
         self.early_stop = early_stop
         self.pair_chunk = min(pair_chunk, _PAIR_BUCKETS[-1])
         self.backend = backend
@@ -218,36 +344,48 @@ class DevicePrePost:
         self.inflight = max(1, int(inflight))
         self.autotune_chunk = bool(autotune_chunk)
         self._widths: Dict[int, int] = {}
+        self._memory_bytes: "int | None" = None
 
     def mine(self, db: Sequence[Sequence[Hashable]], minsup: int,
              ) -> Tuple[ItemsetSupports, DevicePrePostStats]:
         if minsup < 1:
             raise ValueError("minsup must be an absolute count >= 1")
+        return self.mine_packed(BitmapDB.from_db(db, minsup), minsup)
+
+    def mine_packed(self, bdb: BitmapDB, minsup: int,
+                    ) -> Tuple[ItemsetSupports, DevicePrePostStats]:
+        """Mine a packed :class:`BitmapDB` to the complete itemset ->
+        support map on the host (``BitmapMiner.mine_packed``'s
+        contract); rows below ``minsup`` are left out."""
+        if minsup < 1:
+            raise ValueError("minsup must be an absolute count >= 1")
+        job = self._jobs
+        self._jobs += 1
+        with span("mine", job=job):
+            return self._mine_packed(bdb, minsup)
+
+    def _mine_packed(self, bdb: BitmapDB, minsup: int,
+                     ) -> Tuple[ItemsetSupports, DevicePrePostStats]:
         stats = DevicePrePostStats()
         t0 = time.perf_counter()
-
-        tree = PPCTree(db, minsup)
-        order_asc = list(reversed(tree.order_desc))
-        out: ItemsetSupports = {}
-        arrays: List[np.ndarray] = []
-        for it in order_asc:
-            out[frozenset((it,))] = tree.item_support[it]
-            stats.nodes += 1
-            # host-sync: pack-time host PPC-tree N-lists; no device value
-            arrays.append(np.asarray(tree.nlists[it], np.int32).reshape(-1, 3))
-
-        pool = NListPool(capacity=max(
-            64, 2 * sum(nl_pad_len(max(len(a), 1)) for a in arrays)))
-        rows = pool.alloc_rows([len(a) for a in arrays])
-        if len(arrays):
-            pool.write_rows(rows, arrays)
-        root = ClassNode(
-            itemsets=[(it,) for it in order_asc],
-            # host-sync: pack-time host metadata; no device value touched
-            rows=np.asarray(rows, np.int32),
-            supports=np.asarray([tree.item_support[it] for it in order_asc],
-                                np.int32),
-            payload=np.asarray([len(a) for a in arrays], np.int32))
+        with span("ppc.build", acc=(stats, "build_s")):
+            lists = build_nlists(bdb, minsup)
+            pool = NListPool(capacity=max(64, 2 * int(
+                nl_pad_len_np(np.maximum(lists.lengths, 1)).sum())))
+            rows = pool.alloc_rows(lists.lengths)
+            if rows.size:
+                pool.write_rows(rows, np.split(
+                    lists.codes, np.cumsum(lists.lengths)[:-1]))
+        # The search runs in ascending support (oracle.mine_prepost).
+        order_asc, rows = lists.items[::-1], rows[::-1]
+        supports, lengths = lists.supports[::-1], lists.lengths[::-1]
+        out: ItemsetSupports = {frozenset((it,)): int(s)
+                                for it, s in zip(order_asc, supports,
+                                                 strict=True)}
+        stats.nodes += len(out)
+        root = ClassNode(itemsets=[(it,) for it in order_asc], rows=rows,
+                         supports=supports.astype(np.int32),
+                         payload=lengths.astype(np.int32))
 
         self._minsup = minsup
         self._pool = pool
@@ -285,14 +423,21 @@ class DevicePrePost:
         return nl_pad_len_np(np.maximum(cols["u_len"], cols["v_len"]))
 
     def _width_for_bucket(self, bucket: int) -> int:
-        """Autotuned chunk width for one operand length bucket: a pair
-        whose longest operand sits in bucket ``b`` moves ~3*b code
-        words, so short-operand chunks widen proportionally (floored at
-        ``pair_chunk`` — autotuning never narrows a chunk)."""
+        """Chunk width for pairs whose longest operand sits in length
+        bucket ``b``: ``pair_chunk``, or with ``autotune_chunk`` the
+        autotuned width (a pair moves ~3*b code words, so short-operand
+        chunks widen proportionally, floored at ``pair_chunk``); then
+        capped by device memory, since such a chunk gathers at widths
+        ``lu, lv <= b`` (``core.bitmap.nl_hbm_pair_cap``)."""
         w = self._widths.get(bucket)
         if w is None:
-            w = chunk_width_for(3 * bucket, self.pair_chunk,
-                                _PAIR_BUCKETS, 3 * NL_REF_LEN)
+            w = self.pair_chunk
+            if self.autotune_chunk:
+                w = chunk_width_for(3 * bucket, self.pair_chunk,
+                                    _PAIR_BUCKETS, 3 * NL_REF_LEN)
+            if self._memory_bytes is None:
+                self._memory_bytes = device_memory_bytes()
+            w = min(w, nl_hbm_pair_cap(bucket, self._memory_bytes))
             self._widths[bucket] = w
         return w
 
@@ -301,14 +446,17 @@ class DevicePrePost:
         """Per-pair chunk-width cap (ISSUE 7), evaluated on the sorted
         columns: pairs are already ordered by length bucket
         (``chunk_sort_key``), so the caps are non-increasing and the
-        scheduler's greedy slicer packs each bucket at its own width."""
-        if not self.autotune_chunk:
-            return None
+        scheduler's greedy slicer packs each bucket at its own width.
+        ``None`` (fixed ``pair_chunk`` strides) where no bucket of the
+        group is narrowed and autotuning is off."""
         buckets = nl_pad_len_np(np.maximum(cols["u_len"], cols["v_len"]))
-        widths = np.empty(buckets.size, np.int64)
-        for b in np.unique(buckets):
-            widths[buckets == b] = self._width_for_bucket(int(b))
-        return widths
+        keys = np.unique(buckets)
+        # host-sync: host chunk-width table; no device value touched
+        caps = np.asarray([self._width_for_bucket(int(b)) for b in keys],
+                          np.int64)
+        if not self.autotune_chunk and caps.min() >= self.pair_chunk:
+            return None
+        return caps[np.searchsorted(keys, buckets)]
 
     def evaluate_pairs(self, cols: Dict[str, np.ndarray],
                        ) -> PendingMergeResult:
@@ -346,12 +494,15 @@ class DevicePrePost:
 
         def pad(arr, fill=0):
             return bucket_pad(arr, n, _PAIR_BUCKETS, fill)
+        u_len_p = pad(u_len)
         raw = ops.nlist_presize(
-            pool.codes, pad(u_off), pad(u_len), pad(v_off), pad(v_len),
+            pool.codes, pad(u_off), u_len_p, pad(v_off), pad(v_len),
             pad(cols["rho_v"]), np.int32(self._minsup),
             lu=lu, lv=lv, early_stop=self.early_stop,
             backend=self.backend)
         stats.device_calls += 1
+        stats.nl_codes += int(u_len.sum()) + int(v_len.sum())
+        stats.nl_gather_codes += u_len_p.size * (lu + lv)
         return PendingMergeResult(self, n, cols["u_row"], cols["v_row"],
                                   u_len, v_len, lu, lv, raw)
 

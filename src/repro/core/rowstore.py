@@ -472,13 +472,13 @@ class NListPool:
                    code_arrays: Sequence[np.ndarray]) -> None:
         """Upload row contents from host (packing time only: the level-1
         N-lists come out of the PPC-tree build).  One scatter."""
-        idx = np.concatenate([
-            np.arange(self._row_off[int(r)],
-                      self._row_off[int(r)] + len(a), dtype=np.int64)
-            for r, a in zip(rows, code_arrays, strict=True)])
         # host-sync: pack-time host staging for the one h2d scatter below
         vals = np.concatenate([np.asarray(a, np.int32).reshape(-1, 3)
                                for a in code_arrays])
+        # host-sync: pack-time host list lengths; no device value touched
+        lengths = np.asarray([len(a) for a in code_arrays], np.int64)
+        idx = (np.repeat(self.offsets(rows) - (np.cumsum(lengths) - lengths),
+                         lengths) + np.arange(len(vals))).astype(np.int32)
         self.codes = self.codes.at[jnp.asarray(idx)].set(jnp.asarray(vals))
         self.upload_bytes += idx.nbytes + vals.nbytes
 
@@ -493,11 +493,12 @@ class NListPool:
             return np.asarray(self.codes[off:off + ln])
 
     def _grow(self, need: int) -> None:
-        old = self.capacity
-        new = _round_capacity(max(2 * old, need))
-        self.codes = jnp.concatenate(
-            [self.codes, jnp.zeros((new - old, 3), jnp.int32)])
-        self.grows += 1
+        with span("pool.grow"):
+            old = self.capacity
+            new = _round_capacity(max(2 * old, need))
+            self.codes = jnp.concatenate(
+                [self.codes, jnp.zeros((new - old, 3), jnp.int32)])
+            self.grows += 1
 
     def _tight_mass(self) -> int:
         """Total bucketed mass after shrinking every live extent to the
@@ -518,6 +519,10 @@ class NListPool:
         needed).  Free lists and the bump pointer are rebuilt from
         scratch: everything past the packed region is virgin capacity.
         """
+        with span("pool.compact"):
+            self._compact(reserve, backend)
+
+    def _compact(self, reserve: int, backend: str) -> None:
         from repro.kernels import ops
 
         free_rows = set(self._free_rows)

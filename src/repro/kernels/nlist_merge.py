@@ -21,9 +21,10 @@ values.  On the mining hot path this kernel is the merge phase of the
 *pre-pass* dispatch (``ops.nlist_presize``, ISSUE 5): its match table
 stays on device while the host allocates tight extents for the
 surviving children only, and the separate scatter dispatch
-(``ops.nlist_scatter``) Z-merges it into the pool — the merge loop
-runs exactly once per candidate, and dead candidates are never
-scattered.  The one-dispatch composition (``ops.nlist_extend``, same
+(``ops.nlist_scatter``) Z-merges it with :func:`nlist_zmerge`, the
+second kernel of this file, and writes the children into the pool —
+the merge loop runs exactly once per candidate, and dead candidates
+are never scattered.  The one-dispatch composition (``ops.nlist_extend``, same
 kernel, survivor-gated scatter fused behind it) remains the
 micro-bench API.
 """
@@ -44,10 +45,10 @@ from .spec import (SMEM_BYTES, pair_spec, resolve_interpret, smem_out,
                    smem_table)
 
 
-def _kernel(early_stop: bool, lu: int,
+def _kernel(early_stop: bool,
             minsup_ref, up_ref, upo_ref, uf_ref, vp_ref, vpo_ref, vf_ref,
             nu_ref, nv_ref, rho_ref,
-            slot_ref, mass_ref, cmp_ref, chk_ref, alive_ref):
+            slot_ref, mass_ref, cmp_ref, chk_ref, alive_ref, end_ref):
     """One candidate pair: two-pointer NL merge.
 
     minsup_ref: (1,) SMEM                — scalar threshold
@@ -55,22 +56,18 @@ def _kernel(early_stop: bool, lu: int,
     vp/vpo/vf_ref: (1, 1, lv) SMEM       — V rows
     nu/nv/rho_ref: (1, 1, 1) SMEM        — actual lengths + sibling support
     slot_ref: (1, 1, lu) SMEM            — matched V index per U slot
-    mass_ref/cmp_ref/chk_ref/alive_ref: (1, 1, 1) SMEM outputs
+    mass_ref/cmp_ref/chk_ref/alive_ref/end_ref: (1, 1, 1) SMEM outputs
 
     Every access is a scalar at a data-dependent index, so the rows live
-    in SMEM (scalar memory), not VMEM.
+    in SMEM (scalar memory), not VMEM.  Slot ``i`` is written once, when
+    the merge leaves U code ``i`` (its V index, or the sentinel); slots
+    from ``end`` (the final ``i``) on are never written, and the caller
+    masks them to the sentinel with one vector pass.
     """
     minsup = minsup_ref[0]
     nu = nu_ref[0, 0, 0]
     nv = nv_ref[0, 0, 0]
     rho = rho_ref[0, 0, 0]
-
-    # Unmatched slots must read back as sentinel: clear the row first.
-    def clear(i, c):
-        slot_ref[0, 0, i] = jnp.int32(NL_SENTINEL)
-        return c
-
-    jax.lax.fori_loop(0, lu, clear, 0)
 
     def cond(st):
         i, j, _, _, _, _, alive = st
@@ -87,7 +84,7 @@ def _kernel(early_stop: bool, lu: int,
         yj_f = vf_ref[0, 0, j]
         is_desc = jnp.logical_and(xi_pre > yj_pre, xi_post < yj_post)
         adv = jnp.logical_or(is_desc, xi_pre <= yj_pre)
-        slot_ref[0, 0, i] = jnp.where(is_desc, j, slot_ref[0, 0, i])
+        slot_ref[0, 0, i] = jnp.where(is_desc, j, jnp.int32(NL_SENTINEL))
         z_mass = z_mass + jnp.where(is_desc, xi_f, 0)
         skip = skip + jnp.where(adv, 0, yj_f)
         checks = checks + jnp.where(adv, 0, 1)
@@ -99,12 +96,13 @@ def _kernel(early_stop: bool, lu: int,
 
     init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
             jnp.int32(0), jnp.int32(0), jnp.bool_(True))
-    _, _, z_mass, _, cmps, checks, alive = jax.lax.while_loop(
+    end, _, z_mass, _, cmps, checks, alive = jax.lax.while_loop(
         cond, body, init)
     mass_ref[0, 0, 0] = z_mass
     cmp_ref[0, 0, 0] = cmps
     chk_ref[0, 0, 0] = checks
     alive_ref[0, 0, 0] = alive.astype(jnp.int32)
+    end_ref[0, 0, 0] = end
 
 
 def _row_buffers(lu: int, lv: int) -> int:
@@ -149,25 +147,115 @@ def nlist_merge(
     v_row = pair_spec((1, lv), smem, buffers=buffers)
     one = pair_spec((1, 1), smem)
 
-    kernel = functools.partial(_kernel, early_stop, lu)
-    out_slot, z_mass, cmps, checks, alive_i = pl.pallas_call(
+    kernel = functools.partial(_kernel, early_stop)
+    out_slot, z_mass, cmps, checks, alive_i, end = pl.pallas_call(
         kernel,
         grid=(n_pairs,),
         in_specs=[
             pl.BlockSpec(memory_space=smem),  # minsup (whole array)
             u_row, u_row, u_row, v_row, v_row, v_row, one, one, one,
         ],
-        out_specs=[u_row, one, one, one, one],
+        out_specs=[u_row, one, one, one, one, one],
         out_shape=[
             jax.ShapeDtypeStruct((n_pairs, 1, lu), jnp.int32),
             smem_out(n_pairs),
             smem_out(n_pairs),
             smem_out(n_pairs),
             smem_out(n_pairs),
+            smem_out(n_pairs),
         ],
         interpret=interpret,
+        name="nlist_merge",
     )(minsup_arr, *(smem_table(x) for x in (
         u_pre, u_post, u_freq, v_pre, v_post, v_freq, u_len, v_len, rho_v)))
     alive = alive_i[:, 0, 0].astype(jnp.bool_)
     support = jnp.where(alive, z_mass[:, 0, 0], 0)  # aborted => < minsup
-    return out_slot[:, 0, :], support, cmps[:, 0, 0], checks[:, 0, 0], alive
+    left = jnp.arange(lu, dtype=jnp.int32)[None, :] < end[:, 0, :]
+    out_slot = jnp.where(left, out_slot[:, 0, :], jnp.int32(NL_SENTINEL))
+    return out_slot, support, cmps[:, 0, 0], checks[:, 0, 0], alive
+
+
+def _zmerge_kernel(slot_ref, uf_ref, vp_ref, vpo_ref, nu_ref,
+                   zpre_ref, zpost_ref, zfreq_ref, clen_ref):
+    """One pair's Z-merge (Alg. 3 line 31) over its match table: walk
+    the ``nu`` U slots in order; a matched slot whose V index differs
+    from the previous match opens a child (that V code's pre and post),
+    and every matched slot adds its U frequency to the open child.
+
+    slot_ref/uf_ref: (1, 1, lu) SMEM     — match table, U frequencies
+    vp/vpo_ref: (1, 1, lv) SMEM          — V pre and post
+    nu_ref: (1, 1, 1) SMEM               — U length
+    zpre/zpost/zfreq_ref: (1, 1, lu) SMEM — children, at the row's front
+    clen_ref: (1, 1, 1) SMEM             — child count
+    """
+    def body(i, st):
+        k, last, mass = st
+        j = slot_ref[0, 0, i]
+        matched = j != jnp.int32(NL_SENTINEL)
+        opens = jnp.logical_and(matched, j != last)
+        k = k + opens.astype(jnp.int32)
+        mass = jnp.where(opens, 0, mass) + jnp.where(matched,
+                                                     uf_ref[0, 0, i], 0)
+
+        @pl.when(matched)
+        def _():
+            at = k - 1
+            zpre_ref[0, 0, at] = vp_ref[0, 0, j]
+            zpost_ref[0, 0, at] = vpo_ref[0, 0, j]
+            zfreq_ref[0, 0, at] = mass
+
+        return k, jnp.where(matched, j, last), mass
+
+    k, _, _ = jax.lax.fori_loop(0, nu_ref[0, 0, 0], body,
+                                (jnp.int32(0), jnp.int32(-1), jnp.int32(0)))
+    clen_ref[0, 0, 0] = k
+
+
+def _zmerge_buffers(lu: int, lv: int) -> int:
+    """As :func:`_row_buffers`, for the Z-merge's rows: the match table,
+    U frequencies and three child rows of ``lu``, V pre and post of
+    ``lv``."""
+    row_bytes = 4 * (5 * lu + 2 * lv)
+    if 2 * row_bytes <= SMEM_BYTES // 2:
+        return 2
+    if row_bytes <= SMEM_BYTES - (SMEM_BYTES >> 3):
+        return 1
+    raise ValueError(
+        f"N-list rows of {lu} and {lv} codes exceed the TPU's scalar "
+        f"memory ({SMEM_BYTES} bytes); mine them with backend='jnp'")
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def nlist_zmerge(out_slot: jnp.ndarray, u_freq: jnp.ndarray,   # (P, Lu)
+                 v_pre: jnp.ndarray, v_post: jnp.ndarray,      # (P, Lv)
+                 u_len: jnp.ndarray,                           # (P,)
+                 *, interpret: "bool | None" = None,
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
+                            jnp.ndarray]:
+    """Pallas Z-merge.  Returns ``(zpre, zpost, zfreq, child_len)``
+    bit-exact vs ``ref._nl_zmerge``: the children at the front of each
+    row, zero past ``child_len``.  The scalar walk costs ``|U|`` steps
+    per pair where the vector form scatters and gathers every slot."""
+    interpret = resolve_interpret(interpret)
+    n_pairs, lu = out_slot.shape
+    _, lv = v_pre.shape
+    smem = pltpu.SMEM
+    buffers = _zmerge_buffers(lu, lv)
+    u_row = pair_spec((1, lu), smem, buffers=buffers)
+    v_row = pair_spec((1, lv), smem, buffers=buffers)
+    one = pair_spec((1, 1), smem)
+    row = jax.ShapeDtypeStruct((n_pairs, 1, lu), jnp.int32)
+    zpre, zpost, zfreq, clen = pl.pallas_call(
+        _zmerge_kernel,
+        grid=(n_pairs,),
+        in_specs=[u_row, u_row, v_row, v_row, one],
+        out_specs=[u_row, u_row, u_row, one],
+        out_shape=[row, row, row, smem_out(n_pairs)],
+        interpret=interpret,
+        name="nlist_zmerge",
+    )(*(smem_table(x) for x in (out_slot, u_freq, v_pre, v_post, u_len)))
+    child_len = clen[:, 0, 0]
+    live = jnp.arange(lu, dtype=jnp.int32)[None, :] < child_len[:, None]
+    return (jnp.where(live, zpre[:, 0, :], 0),
+            jnp.where(live, zpost[:, 0, :], 0),
+            jnp.where(live, zfreq[:, 0, :], 0), child_len)

@@ -575,6 +575,22 @@ def nlist_intersect(u_pre, u_post, u_freq, v_pre, v_post, v_freq,
                                     early_stop=early_stop)
 
 
+def _nl_zmerge_scatter(codes, out_slot, u_len, u_freq, v_pre, v_post,
+                       out_off, backend):
+    """Z-merge (the Pallas scalar walk, or the jnp vector form) and the
+    child write shared by the N-list dispatches."""
+    if backend in _PALLAS:
+        from .nlist_merge import nlist_zmerge
+        zpre, zpost, zfreq, child_len = nlist_zmerge(
+            out_slot, u_freq, v_pre, v_post, u_len,
+            interpret=backend == "interpret")
+    else:
+        zpre, zpost, zfreq, child_len = _ref._nl_zmerge(out_slot, u_freq,
+                                                        v_pre, v_post)
+    return _ref._nl_write(codes, zpre, zpost, zfreq, child_len,
+                          out_off), child_len
+
+
 def _nl_merge_backend(codes, u_off, u_len, v_off, v_len, rho_v, minsup,
                       *, lu, lv, early_stop, backend):
     """Shared gather + two-pointer-merge body of the N-list dispatches."""
@@ -606,8 +622,8 @@ def _nlist_extend_impl(codes, u_off, u_len, v_off, v_len, out_off, rho_v,
     # frequency gate covers both ES deaths and plain infrequency.
     keep = support >= minsup
     out_off_eff = jnp.where(keep, out_off, jnp.int32(codes.shape[0]))
-    codes, child_len = _ref._nl_zmerge_scatter(
-        codes, out_slot, u_freq, v_pre, v_post, out_off_eff)
+    codes, child_len = _nl_zmerge_scatter(
+        codes, out_slot, u_len, u_freq, v_pre, v_post, out_off_eff, backend)
     return codes, child_len, support, cmps, checks, alive
 
 
@@ -646,11 +662,12 @@ def nlist_extend(codes, u_off, u_len, v_off, v_len, out_off, rho_v, minsup,
                    static_argnames=("lu", "lv", "early_stop", "backend"))
 def _nlist_presize_impl(codes, u_off, u_len, v_off, v_len, rho_v,
                         minsup, *, lu, lv, early_stop, backend):
-    merged, _, _, _ = _nl_merge_backend(
-        codes, u_off, u_len, v_off, v_len, rho_v, minsup,
-        lu=lu, lv=lv, early_stop=early_stop, backend=backend)
-    out_slot, support, cmps, checks, alive = merged
-    _, _, child_len = _ref._nl_group_starts(out_slot)
+    with jax.named_scope("dispatch.nlist_merge"):
+        merged, _, _, _ = _nl_merge_backend(
+            codes, u_off, u_len, v_off, v_len, rho_v, minsup,
+            lu=lu, lv=lv, early_stop=early_stop, backend=backend)
+        out_slot, support, cmps, checks, alive = merged
+        _, _, child_len = _ref._nl_group_starts(out_slot)
     return out_slot, child_len, support, cmps, checks, alive
 
 
@@ -679,14 +696,15 @@ def nlist_presize(codes, u_off, u_len, v_off, v_len, rho_v, minsup,
         lu=lu, lv=lv, early_stop=early_stop, backend=b)
 
 
-@functools.partial(jax.jit, static_argnames=("lu", "lv"),
+@functools.partial(jax.jit, static_argnames=("lu", "lv", "backend"),
                    donate_argnums=(0,))
 def _nlist_scatter_impl(codes, out_slot, u_off, u_len, v_off, v_len,
-                        out_off, *, lu, lv):
-    _, _, u_freq = _ref._nl_gather(codes, u_off, u_len, lu)
-    v_pre, v_post, _ = _ref._nl_gather(codes, v_off, v_len, lv)
-    return _ref._nl_zmerge_scatter(codes, out_slot, u_freq, v_pre, v_post,
-                                   out_off)
+                        out_off, *, lu, lv, backend):
+    with jax.named_scope("dispatch.nlist_scatter"):
+        _, _, u_freq = _ref._nl_gather(codes, u_off, u_len, lu)
+        v_pre, v_post, _ = _ref._nl_gather(codes, v_off, v_len, lv)
+        return _nl_zmerge_scatter(codes, out_slot, u_len, u_freq, v_pre,
+                                  v_post, out_off, backend)
 
 
 def nlist_scatter(codes, out_slot, u_off, u_len, v_off, v_len, out_off,
@@ -698,13 +716,15 @@ def nlist_scatter(codes, out_slot, u_off, u_len, v_off, v_len, out_off,
     :func:`nlist_presize` match table and scatters the compacted child
     N-lists into their tight extents at ``out_off``; callers pass
     ``out_off >= capacity`` for non-survivors and padding, which makes
-    the scatter survivor-only by construction.  Gather/Z-merge/scatter
-    are pure vectorized jnp on every backend.  ``codes`` is DONATED:
-    callers must replace their handle.  Returns ``(codes, child_len)``.
+    the scatter survivor-only by construction.  The Z-merge is the
+    Pallas scalar walk (``nlist_merge.nlist_zmerge``) on the Pallas
+    backends and the vector form elsewhere; the write updates each
+    child's codes in place.  ``codes`` is DONATED: callers must replace
+    their handle.  Returns ``(codes, child_len)``.
     """
-    del backend
     return _nlist_scatter_impl(
         codes, jnp.asarray(out_slot, jnp.int32),
         jnp.asarray(u_off, jnp.int32), jnp.asarray(u_len, jnp.int32),
         jnp.asarray(v_off, jnp.int32), jnp.asarray(v_len, jnp.int32),
-        jnp.asarray(out_off, jnp.int32), lu=lu, lv=lv)
+        jnp.asarray(out_off, jnp.int32), lu=lu, lv=lv,
+        backend=_resolve(backend))

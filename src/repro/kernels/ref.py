@@ -666,14 +666,17 @@ def _nl_gather(codes, off, length, width: int):
     """Gather padded (pre, post, freq) rows from the pool slab.
 
     ``codes int32 (cap, 3)``, ``off/length int32 (P,)`` -> three
-    ``(P, width)`` arrays, sentinel-padded past each row's length."""
-    cap = codes.shape[0]
-    idx = off[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    ``(P, width)`` arrays, sentinel-padded past each row's length.  Each
+    row is one contiguous window of the slab (a slice gather, which the
+    TPU moves as one copy per row, not one per code); the slab is padded
+    by ``width`` so that no window is clamped."""
+    cols = jnp.pad(codes, ((0, width), (0, 0))).T              # (3, cap+w)
+    g = jax.vmap(lambda o: jax.lax.dynamic_slice_in_dim(
+        cols, o, width, axis=1))(off)                          # (P, 3, w)
     mask = jnp.arange(width, dtype=jnp.int32)[None, :] < length[:, None]
-    g = jnp.take(codes, jnp.minimum(idx, cap - 1), axis=0)
-    pre = jnp.where(mask, g[..., 0], NL_SENTINEL)
-    post = jnp.where(mask, g[..., 1], 0)
-    freq = jnp.where(mask, g[..., 2], 0)
+    pre = jnp.where(mask, g[:, 0], NL_SENTINEL)
+    post = jnp.where(mask, g[:, 1], 0)
+    freq = jnp.where(mask, g[:, 2], 0)
     return pre, post, freq
 
 
@@ -694,18 +697,16 @@ def _nl_group_starts(out_slot):
     return valid, start, child_len
 
 
-def _nl_zmerge_scatter(codes, out_slot, u_freq, v_pre, v_post, out_off):
-    """Device Z-merge (Alg. 3 line 31) + child scatter into the pool.
+def _nl_zmerge(out_slot, u_freq, v_pre, v_post):
+    """Device Z-merge (Alg. 3 line 31): the compacted child N-lists.
 
     Consecutive U slots matching the same V ancestor code are one child
     element whose frequency is the group's U-frequency mass (see
-    :func:`_nl_group_starts`).  Children are compacted to the front of
-    their extents at ``out_off`` (offsets past the slab capacity are
-    dropped — pair padding / non-survivors).
-
-    Returns ``(codes, child_len)``."""
+    :func:`_nl_group_starts`).  Returns ``(zpre, zpost, zfreq,
+    child_len)``: children at the front of each ``(P, Lu)`` row, zero
+    past ``child_len``.  ``kernels/nlist_merge.py::nlist_zmerge`` must
+    reproduce it bit-for-bit."""
     P, Lu = out_slot.shape
-    cap = codes.shape[0]
     valid, start, child_len = _nl_group_starts(out_slot)
     gid = jnp.cumsum(start.astype(jnp.int32), axis=1) - 1
 
@@ -718,14 +719,50 @@ def _nl_zmerge_scatter(codes, out_slot, u_freq, v_pre, v_post, out_off):
     rep = jnp.zeros((P, Lu), jnp.int32).at[
         rows, jnp.where(start, gid, Lu)].set(
         jnp.where(start, out_slot, 0), mode="drop")
-    zpre = jnp.take_along_axis(v_pre, rep, axis=1)
-    zpost = jnp.take_along_axis(v_post, rep, axis=1)
+    live = jnp.arange(Lu, dtype=jnp.int32)[None, :] < child_len[:, None]
+    zpre = jnp.where(live, jnp.take_along_axis(v_pre, rep, axis=1), 0)
+    zpost = jnp.where(live, jnp.take_along_axis(v_post, rep, axis=1), 0)
+    return zpre, zpost, zfreq, child_len
 
-    k = jnp.arange(Lu, dtype=jnp.int32)[None, :]
-    dest = jnp.where(k < child_len[:, None], out_off[:, None] + k, cap)
-    child = jnp.stack([zpre, zpost, zfreq], axis=-1)
-    codes = codes.at[dest].set(child, mode="drop")
-    return codes, child_len
+
+def _nl_write(codes, zpre, zpost, zfreq, child_len, out_off):
+    """Write each child row's first ``child_len`` codes into the pool at
+    ``out_off`` (offsets past the slab capacity are dropped — pair
+    padding / non-survivors); every other code of the slab is kept.
+
+    One step per written child, in a loop: read the ``Lu``-code slab
+    window at the child's offset (clamped into the slab), overlay the
+    child and write the window back, so the slab is updated in place
+    and no code outside ``[out_off, out_off + child_len)`` changes."""
+    cap = codes.shape[0]
+    P, Lu = zpre.shape
+    width = min(Lu, cap)
+    keep = out_off < cap
+    order = jnp.nonzero(keep, size=P, fill_value=0)[0]
+    k = jnp.arange(width, dtype=jnp.int32)[:, None]
+
+    def body(n, codes):
+        p = order[n]
+        start = jnp.minimum(out_off[p], cap - width)
+        shift = out_off[p] - start
+        child = jnp.pad(jnp.stack([zpre[p], zpost[p], zfreq[p]], axis=-1),
+                        ((width, 0), (0, 0)))             # (w + Lu, 3)
+        new = jax.lax.dynamic_slice_in_dim(child, width - shift, width)
+        old = jax.lax.dynamic_slice_in_dim(codes, start, width)
+        mine = jnp.logical_and(k >= shift, k < shift + child_len[p])
+        return jax.lax.dynamic_update_slice_in_dim(
+            codes, jnp.where(mine, new, old), start, axis=0)
+
+    return jax.lax.fori_loop(0, jnp.sum(keep.astype(jnp.int32)), body, codes)
+
+
+def _nl_zmerge_scatter(codes, out_slot, u_freq, v_pre, v_post, out_off):
+    """Device Z-merge (:func:`_nl_zmerge`) + child write into the pool
+    (:func:`_nl_write`).  Returns ``(codes, child_len)``."""
+    zpre, zpost, zfreq, child_len = _nl_zmerge(out_slot, u_freq, v_pre,
+                                               v_post)
+    return _nl_write(codes, zpre, zpost, zfreq, child_len, out_off), \
+        child_len
 
 
 @functools.partial(jax.jit, static_argnames=("lu", "lv", "early_stop"))

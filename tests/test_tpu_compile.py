@@ -26,7 +26,7 @@ from repro.kernels import ops
 from repro.kernels.bitmap_diff import bitmap_diff_es
 from repro.kernels.bitmap_intersect import bitmap_intersect_es
 from repro.kernels.compact import compact_gather
-from repro.kernels.nlist_merge import nlist_merge
+from repro.kernels.nlist_merge import nlist_merge, nlist_zmerge
 
 NB, BW = 242, 128                  # kosarak-paper: 990,000 TIDs
 PAIRS = 256
@@ -102,6 +102,14 @@ def test_nlist_merge_kernel_compiles(chip, length):
     _compile(lambda *a: nlist_merge(*a, early_stop=True, interpret=False),
              row, row, row, row, row, row, chip((PAIRS,)), chip((PAIRS,)),
              chip((PAIRS,)), chip(()))
+
+
+@pytest.mark.parametrize("length", [2048, 32768])
+def test_nlist_zmerge_kernel_compiles(chip, length):
+    """The Z-merge's rows fit scalar memory up to the largest bucket."""
+    row = chip((PAIRS, length))
+    _compile(lambda *a: nlist_zmerge(*a, interpret=False),
+             row, row, row, row, chip((PAIRS,)))
 
 
 def test_nlist_merge_refuses_rows_past_scalar_memory(chip):

@@ -19,6 +19,7 @@ import repro.core.eclat as eclat_mod
 import repro.core.trace as trace_mod
 from repro.core.bitmap import PAIR_CHUNK_BUCKETS, BitmapDB
 from repro.core.eclat import BitmapMiner
+from repro.core.prepost import DevicePrePost
 from repro.core.frontier import ClassNode, FrontierScheduler
 from repro.core.rowstore import DeviceRowStore
 from repro.core.trace import span
@@ -37,9 +38,9 @@ def _db(seed=0, n_items=20, n_trans=80, p=0.35):
     return [t for t in db if t]
 
 
-def _traced(fn, tmp_path):
+def _traced(fn, tmp_path, names=SPANS):
     """Run ``fn`` under the profiler; return its result and the host
-    events named in ``SPANS`` as ``(start, end, name, stats)``."""
+    events named in ``names`` as ``(start, end, name, stats)``."""
     with jax.profiler.trace(str(tmp_path)):
         out = fn()
     path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
@@ -52,7 +53,7 @@ def _traced(fn, tmp_path):
                 events.extend(
                     (e.start_ns, e.start_ns + e.duration_ns, e.name,
                      dict(e.stats))
-                    for e in line.events if e.name in SPANS)
+                    for e in line.events if e.name in names)
     return out, events
 
 
@@ -242,3 +243,46 @@ def test_compaction_uploads_its_permutation(monkeypatch):
     (real, slab), = built
     assert st.upload_bytes == real + sum(perms)
     assert real < slab
+
+
+# ---------------------------------------------------------------------------
+# PrePost+: tree build, pool and N-list dispatches
+# ---------------------------------------------------------------------------
+
+def test_prepost_job_spans_and_dispatch_scopes(tmp_path):
+    """``mine`` and ``ppc.build`` open once per job; ``pool.grow`` and
+    ``pool.compact`` once per growth and compaction, inside their job;
+    the two N-list dispatches carry their ``dispatch.*`` scopes."""
+    db, ms = _db(), 4
+    miner = DevicePrePost(pair_chunk=8, compact_occupancy=1.0)
+    bdb = BitmapDB.from_db(db, ms)
+
+    def two_jobs():
+        return [miner.mine_packed(bdb, ms) for _ in range(2)]
+
+    names = ("mine", "ppc.build", "pool.grow", "pool.compact")
+    (first, second), events = _traced(two_jobs, tmp_path, names)
+    assert first[0] == second[0]
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev[2], []).append(ev)
+    for name in ("mine", "ppc.build"):
+        assert sorted(ev[3]["job"] for ev in by_name[name]) == [0, 1]
+    for name, count in (("pool.grow", "grows"),
+                        ("pool.compact", "compactions")):
+        per_job = getattr(first[1], count)
+        assert per_job > 0, count
+        assert len(by_name[name]) == 2 * per_job
+    for ev in events:
+        assert any(_inside(m, ev, ("job",)) for m in by_name["mine"]), ev
+    assert first[1].build_s > 0.0
+
+    codes, v = np.zeros((64, 3), np.int32), np.zeros(8, np.int32)
+    merge = ops._nlist_presize_impl.lower(
+        codes, v, v, v, v, v, np.int32(1), lu=8, lv=8, early_stop=True,
+        backend="interpret").compile().as_text()
+    scatter = ops._nlist_scatter_impl.lower(
+        codes, np.zeros((8, 8), np.int32), v, v, v, v, v, lu=8, lv=8,
+        backend="interpret").compile().as_text()
+    assert "dispatch.nlist_merge" in merge
+    assert "dispatch.nlist_scatter" in scatter
