@@ -1,9 +1,10 @@
-"""The PrePost+ cell's engine adapter and its four per-layer readers.
+"""The PrePost+ cell's engine adapter and its per-layer readers.
 
 The adapter's job runs a tiny packed replica on the CPU and must match
-the plain reference; each reader is fed hand-made records and returns
-None where what it reads is absent (a trace without the kernel, a
-program without the counters).
+the plain reference; each of the cell's own readers is fed hand-made
+records and returns None where what it reads is absent (a program
+without the counters).  The cell also reports five metrics of the
+bitmap cells, read from the same job record and device trace.
 """
 
 import pytest
@@ -11,6 +12,9 @@ import pytest
 from bench import data, harness, reference
 
 CELL = "pumsb-paper.mid"
+OWN = {"ppc.build_ms", "nlist.pad_frac", "nlist.es_abort_frac"}
+SHARED = {"miners.device_calls", "sched.assemble_ms", "sched.resolve_ms"}
+DEVICE = {"dispatch.device_ms", "device.idle_frac"}
 
 
 def _reader(name):
@@ -20,9 +24,7 @@ def _reader(name):
 def test_cell_names_the_prepost_engine_and_its_metrics():
     cell = harness.Cell(CELL)
     assert cell.config["engine"] == "prepost"
-    assert set(cell.metric_readers()) == {"ppc.build_ms", "nlist.merge_ms",
-                                          "nlist.pad_frac",
-                                          "nlist.es_abort_frac"}
+    assert set(cell.metric_readers()) == OWN | SHARED | DEVICE
     assert cell.config["params"]["n_cols"] == 74
 
 
@@ -48,17 +50,6 @@ def test_build_ms():
     assert read({"jobs": [{"build_s": None}, {}]}) is None
 
 
-def test_merge_ms():
-    read = _reader("nlist.merge_ms").read
-    ops = [["nlist_merge.3____s32_4096_1_32768", 1.5],
-           ["copy.2", 9.0], ["nlist_merge.1", 0.5]]
-    rec = {"jobs": [{}, {}], "trace": {"device_ops": ops}}
-    assert read(rec) == 1000.0
-    assert read({"jobs": [{}], "trace": {"device_ops": [["copy", 1.0]]}}) \
-        is None
-    assert read({"jobs": [{}], "trace": None}) is None
-
-
 def test_pad_frac():
     read = _reader("nlist.pad_frac").read
     jobs = [{"nl_codes": 10, "nl_gather_codes": 40},
@@ -77,8 +68,8 @@ def test_es_abort_frac():
 
 
 def test_traced_cpu_run_reports_the_counter_metrics():
-    """A CPU trace has no device plane: the kernel's reader is silent and
-    the other three report."""
+    """A CPU trace has no device plane: the readers of the device trace
+    are silent, and those of the job's counters and timers report."""
     cell = harness.Cell(CELL)
     cell.config = dict(cell.config, rungs=[0.25, 0.3],
                        params=dict(cell.config["params"], n_trans=40_000,
@@ -86,5 +77,4 @@ def test_traced_cpu_run_reports_the_counter_metrics():
     line = harness.run_cell(cell, 2**31 + 7, 0.2, True, t_start=0.0,
                             scale=0.01)
     assert line["correct"] is True
-    assert set(line["metrics"]) == {"ppc.build_ms", "nlist.pad_frac",
-                                    "nlist.es_abort_frac"}
+    assert set(line["metrics"]) == OWN | SHARED

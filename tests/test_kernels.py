@@ -12,7 +12,7 @@ from repro.kernels.ref import (bitmap_intersect_es_ref, bitmap_diff_es_ref,
                                bitmap_intersect_full_ref, bitmap_count_ref,
                                flash_attention_ref, embedding_bag_ref,
                                screen_pairs_ref, screen_and_intersect_ref,
-                               screen_and_diff_ref)
+                               screen_and_diff_ref, NL_SENTINEL)
 from repro.kernels.bitmap_diff import bitmap_diff_es
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.segment_embed import embedding_bag
@@ -508,6 +508,95 @@ def test_nlist_merge_pallas_matches_ref(es):
                                "alive"), r, p, strict=True):
             assert np.array_equal(np.asarray(a), np.asarray(b)), (
                 es, minsup, name)
+
+
+def _zmerge_loop(out_slot, u_freq, v_pre, v_post, u_len):
+    """Z-merge written as the plain loop over each pair's U slots."""
+    P, lu = out_slot.shape
+    z = np.zeros((3, P, lu), np.int32)
+    clen = np.zeros(P, np.int32)
+    for p in range(P):
+        k, last = 0, -1
+        for i in range(u_len[p]):
+            j = out_slot[p, i]
+            if j == NL_SENTINEL:
+                continue
+            if j != last:
+                k, last = k + 1, j
+                z[:, p, k - 1] = v_pre[p, j], v_post[p, j], 0
+            z[2, p, k - 1] += u_freq[p, i]
+        clen[p] = k
+    return z[0], z[1], z[2], clen
+
+
+@pytest.mark.parametrize("lu,lv", [(8, 32), (32, 8), (128, 128)])
+def test_nlist_zmerge_pallas_matches_ref(lu, lv):
+    """The Pallas Z-merge (interpret mode) == ref._nl_zmerge == a plain
+    loop on match tables with runs of slots sharing one V code,
+    unmatched slots between them and rows of every length."""
+    from repro.kernels.nlist_merge import nlist_zmerge
+    from repro.kernels.ref import _nl_zmerge
+
+    rng = np.random.default_rng(23)
+    P = 12
+    u_len = rng.integers(0, lu + 1, P).astype(np.int32)
+    u_len[:2] = (0, lu)
+    out_slot = np.full((P, lu), NL_SENTINEL, np.int32)
+    for p in range(P):
+        n = int(u_len[p])
+        steps = rng.integers(0, 3, n)          # 0: same V code as before
+        js = np.minimum(np.cumsum(steps), lv - 1)
+        hit = rng.random(n) < 0.7
+        out_slot[p, :n] = np.where(hit, js, NL_SENTINEL)
+    u_freq = rng.integers(1, 9, (P, lu)).astype(np.int32)
+    v_pre = rng.integers(0, 10**6, (P, lv)).astype(np.int32)
+    v_post = rng.integers(0, 10**6, (P, lv)).astype(np.int32)
+
+    want = _zmerge_loop(out_slot, u_freq, v_pre, v_post, u_len)
+    got_ref = _nl_zmerge(jnp.asarray(out_slot), jnp.asarray(u_freq),
+                         jnp.asarray(v_pre), jnp.asarray(v_post))
+    got_pl = nlist_zmerge(jnp.asarray(out_slot), jnp.asarray(u_freq),
+                          jnp.asarray(v_pre), jnp.asarray(v_post),
+                          jnp.asarray(u_len), interpret=True)
+    assert want[3].max() > 1 and (want[3] < u_len).any()
+    for name, w, r, g in zip(("zpre", "zpost", "zfreq", "child_len"), want,
+                             got_ref, got_pl, strict=True):
+        assert np.array_equal(np.asarray(r), w), ("ref", name)
+        assert np.array_equal(np.asarray(g), w), ("pallas", name)
+
+
+@pytest.mark.parametrize("cap,lu", [(256, 8), (64, 32), (24, 32)])
+def test_nl_write_matches_per_code_scatter(cap, lu):
+    """ref._nl_write (one window per child, clamped into the slab) ==
+    the per-code scatter it replaced: children anywhere in the slab,
+    children that end at its capacity, and offsets past it dropped."""
+    from repro.kernels.ref import _nl_write
+
+    rng = np.random.default_rng(29)
+    P = 9
+    codes = rng.integers(0, 1000, (cap, 3)).astype(np.int32)
+    zs = rng.integers(1, 1000, (3, P, lu)).astype(np.int32)
+    child_len = rng.integers(0, min(lu, cap // 4) + 1, P).astype(np.int32)
+    child_len[0] = min(lu, cap // 4) - 1
+    # disjoint extents from the front; pair 0 ends at the capacity,
+    # the last two lie past it
+    out_off = np.full(P, cap, np.int32)
+    out_off[0] = cap - child_len[0]
+    bump = 0
+    for p in range(1, P - 2):
+        if bump + child_len[p] <= out_off[0]:
+            out_off[p], bump = bump, bump + int(child_len[p])
+    out_off[-1] = cap + 7
+    zs = np.where(np.arange(lu) < child_len[:, None], zs, 0)
+
+    dest = np.where(np.arange(lu) < child_len[:, None],
+                    out_off[:, None] + np.arange(lu), cap)
+    want = jnp.asarray(codes).at[jnp.asarray(dest.ravel())].set(
+        jnp.asarray(np.stack(zs, axis=-1).reshape(-1, 3)), mode="drop")
+    got = _nl_write(jnp.asarray(codes), *(jnp.asarray(z) for z in zs),
+                    jnp.asarray(child_len), jnp.asarray(out_off))
+    assert out_off[0] > cap - min(lu, cap)      # the clamped window
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
