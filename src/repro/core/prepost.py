@@ -61,7 +61,7 @@ from repro.core.bitmap import (NL_LEN_BUCKETS, NL_PAIR_CHUNK_BUCKETS,
                                chunk_width_for, device_memory_bytes,
                                nl_hbm_pair_cap, nl_pad_len, nl_pad_len_np)
 from repro.core.trace import span
-from repro.kernels import ops
+from repro.kernels import nlist_merge, ops
 
 ItemsetSupports = Dict[FrozenSet[Hashable], int]
 
@@ -233,9 +233,14 @@ class PendingMergeResult:
             child_len = np.asarray(child_len[:n])
             support = np.asarray(support[:n])
             alive = np.asarray(alive[:n])
-            cmps_total = int(np.asarray(cmps[:n]).sum())
-            checks_total = int(np.asarray(checks[:n]).sum())
-        stats.comparisons += cmps_total
+            cmps = np.asarray(cmps[:n])
+            checks = np.asarray(checks[:n])
+        checks_total = int(checks.sum())
+        stats.comparisons += int(cmps.sum())
+        # Each comparison advances one pointer: V on a check, else U.
+        stats.nl_fetch_codes += int(nlist_merge.fetched_codes(
+            cmps - checks, checks, self._u_len, self._v_len,
+            self._lu, self._lv).sum())
         if miner.early_stop:
             # One ES bound evaluation per skipped V code — exactly the
             # oracle's es_checks, and aborts are only attributed when
@@ -288,11 +293,15 @@ class DevicePrePostStats(MiningStats, EngineAccounting):
     build and the level-1 N-lists' upload).  ``nl_codes`` counts the real
     operand codes the merge pre-passes gathered (``|U| + |V|`` per
     candidate); ``nl_gather_codes`` counts what they gathered at the
-    padded widths (padded pairs x (``lu`` + ``lv``))."""
+    padded widths (padded pairs x (``lu`` + ``lv``)); ``nl_fetch_codes``
+    counts the operand codes the merge kernel copies of those into
+    scalar memory (``nlist_merge.fetched_codes``: its block rule applied
+    to each pair's pointers, whichever backend merged)."""
 
     build_s: float = 0.0
     nl_codes: int = 0
     nl_gather_codes: int = 0
+    nl_fetch_codes: int = 0
 
     # Legacy names kept as read-only views of the shared accounting.
     @property
@@ -310,6 +319,7 @@ class DevicePrePostStats(MiningStats, EngineAccounting):
     def as_dict(self) -> Dict[str, float]:
         d = super().as_dict()
         d.update(pool_grows=self.pool_grows, peak_codes=self.peak_codes,
+                 nl_fetch_codes=self.nl_fetch_codes,
                  **self.accounting_dict())
         return d
 

@@ -28,7 +28,8 @@ def pair_spec(shape, memory_space=None, *, buffers: int = 2):
     kw = {} if memory_space is None else {"memory_space": memory_space}
     if buffers != 2:
         kw["pipeline_mode"] = pl.Buffered(buffers)
-    return pl.BlockSpec((1,) + tuple(shape), lambda p: (p,) + zeros, **kw)
+    return pl.BlockSpec((1,) + tuple(shape), lambda p, *_: (p,) + zeros,
+                        **kw)
 
 
 def smem_table(x: jnp.ndarray) -> jnp.ndarray:

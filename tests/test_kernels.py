@@ -14,6 +14,7 @@ from repro.kernels.ref import (bitmap_intersect_es_ref, bitmap_diff_es_ref,
                                screen_pairs_ref, screen_and_intersect_ref,
                                screen_and_diff_ref, NL_SENTINEL)
 from repro.kernels.bitmap_diff import bitmap_diff_es
+from repro.kernels.nlist_merge import B_MAX
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.segment_embed import embedding_bag
 
@@ -479,35 +480,115 @@ def test_nlist_presize_scatter_split_matches_ref_and_extend(backend, es):
         assert np.array_equal(sc_codes[untouched], codes[untouched])
 
 
+B = B_MAX                            # the merge kernel's widest block
+
+
+def _merge_batch(rng, lu, lv):
+    """Padded operand rows for the merge kernel: random pairs whose
+    lengths sit on and beside block edges (0, 1, b - 1, b, b + 1, the
+    whole row), then five shaped pairs (on rows of several blocks):
+    V walks every block while U stays on its first code (0, and 2-4
+    with unit V frequencies, which the caller turns into early-stop
+    aborts around V's first block edge), and U walks every block while
+    V stays on its first (1)."""
+    n_pairs = 16
+    bu, bv = min(lu, B), min(lv, B)
+
+    def mk(n, width):
+        pre = np.sort(rng.integers(0, 4 * width, (n, width)), 1)
+        post = rng.integers(0, 4 * width, (n, width))
+        freq = rng.integers(1, 10, (n, width))
+        return [x.astype(np.int32) for x in (pre, post, freq)]
+
+    def edges(b, width):
+        near = [0, 1, b - 1, b, b + 1, width]
+        return np.asarray([x for x in near if 0 <= x <= width], np.int32)
+
+    (up, upo, uf), (vp, vpo, vf) = mk(n_pairs, lu), mk(n_pairs, lv)
+    ul = rng.choice(edges(bu, lu), n_pairs).astype(np.int32)
+    vl = rng.choice(edges(bv, lv), n_pairs).astype(np.int32)
+    rho = rng.integers(0, 100, n_pairs).astype(np.int32)
+    # U's codes lie after every V code, never below one: V advances.
+    for p in (0, 2, 3, 4):
+        up[p], upo[p], vl[p] = 10 * lv, 10 * lv, lv
+        ul[p] = min(lu, bu + 1)
+        rho[p] = 10 ** 6
+    vf[2:5] = 1
+    # U's codes all precede V's first: U advances.
+    up[1], vp[1] = np.arange(lu), lu + np.arange(lv)
+    ul[1], vl[1] = lu, min(lv, bv + 1)
+    return (up, upo, uf, vp, vpo, vf), ul, vl, rho
+
+
 @pytest.mark.parametrize("es", [False, True])
-def test_nlist_merge_pallas_matches_ref(es):
-    """Standalone padded-batch merge: pallas kernel vs the jnp ref."""
+@pytest.mark.parametrize("lu,lv", [(8, 32), (3 * B, 3 * B), (32, 2 * B),
+                                   (2 * B, 128)])
+def test_nlist_merge_pallas_matches_ref(es, lu, lv):
+    """Standalone padded-batch merge: the Pallas kernel, which fetches
+    its operands block by block, vs the jnp ref on all five outputs,
+    with block edges crossed by either pointer and early-stop aborts
+    just before, at and just after V's first block edge."""
     from repro.kernels.ref import nlist_intersect_ref
 
     rng = np.random.default_rng(3)
-    n_pairs, lu, lv = 16, 8, 32
-
-    def mk(n, width):
-        pre = np.sort(rng.integers(0, 500, (n, width)).astype(np.int32), 1)
-        post = rng.integers(0, 500, (n, width)).astype(np.int32)
-        freq = rng.integers(1, 10, (n, width)).astype(np.int32)
-        return pre, post, freq
-
-    up, upo, uf = mk(n_pairs, lu)
-    vp, vpo, vf = mk(n_pairs, lv)
-    ul = rng.integers(1, lu + 1, n_pairs).astype(np.int32)
-    vl = rng.integers(1, lv + 1, n_pairs).astype(np.int32)
-    rho = rng.integers(0, 100, n_pairs).astype(np.int32)
+    rows, ul, vl, rho = _merge_batch(rng, lu, lv)
+    bv = min(lv, B)
     for minsup in (0, 1, 20):
-        r = nlist_intersect_ref(up, upo, uf, vp, vpo, vf, ul, vl, rho,
-                                jnp.int32(minsup), early_stop=es)
-        p = ops.nlist_intersect(up, upo, uf, vp, vpo, vf, ul, vl, rho,
-                                jnp.int32(minsup), early_stop=es,
-                                backend="pallas")
+        # With unit frequencies and no match, the abort comes on the
+        # step that skips V code rho - minsup: bv - 2, bv - 1 and bv.
+        rho[2:5] = minsup + bv + np.arange(-2, 1)
+        r = nlist_intersect_ref(*rows, ul, vl, rho, jnp.int32(minsup),
+                                early_stop=es)
+        p = ops.nlist_intersect(*rows, ul, vl, rho, jnp.int32(minsup),
+                                early_stop=es, backend="pallas")
         for name, a, b in zip(("out_slot", "support", "cmps", "checks",
                                "alive"), r, p, strict=True):
             assert np.array_equal(np.asarray(a), np.asarray(b)), (
                 es, minsup, name)
+        checks = np.asarray(p[3])
+        if lv > B:
+            assert checks[0] == vl[0]           # V crossed every block
+            assert (checks[2:5] == bv + np.arange(-1, 2)).all() or not es
+        if lu > B:
+            assert np.asarray(p[2])[1] - checks[1] == lu   # so did U
+
+
+@pytest.mark.parametrize("case", ["none", "u_ends", "v_walks", "v_edge",
+                                  "past_edge", "v_ends", "narrow"])
+def test_fetched_codes_counts_blocks(case):
+    """``fetched_codes`` against blocks counted by hand: block 0 up to
+    the one after the last block read, at most the list's blocks."""
+    from repro.kernels.nlist_merge import fetched_codes
+
+    w = 4 * B
+    u_adv, v_adv, u_len, v_len, lu, want = {
+        "none": (0, 0, 0, 5, w, 0),                  # no comparison
+        "u_ends": (1, 0, 1, w, w, 1 * B + 2 * B),    # U read 0, V read 0
+        "v_walks": (0, 2 * B + 6, w, w, w, 2 * B + 4 * B),   # V read 2B+5
+        "v_edge": (0, B, w, w, w, 2 * B + 2 * B),    # V read B - 1
+        "past_edge": (0, B + 1, w, w, w, 2 * B + 3 * B),     # V read B
+        "v_ends": (3, B, w, B, w, 2 * B + 1 * B),    # V ran out at B
+        "narrow": (0, 0, 0, 0, 8, 8 + 0),            # U comes whole
+    }[case]
+    got = fetched_codes(np.asarray([u_adv]), np.asarray([v_adv]),
+                        np.asarray([u_len]), np.asarray([v_len]), lu, w)
+    assert got.tolist() == [want]
+
+
+def test_fetched_codes_zero_for_empty_chunk():
+    """A chunk whose lengths are all 0 fetches nothing: the kernel's
+    comparisons and checks are 0, and so is the helper's count."""
+    from repro.kernels.nlist_merge import fetched_codes, nlist_merge
+
+    w = 2 * B
+    rng = np.random.default_rng(4)
+    rows = [rng.integers(0, 99, (4, w)).astype(np.int32) for _ in range(6)]
+    zero = np.zeros(4, np.int32)
+    _, _, cmps, checks, _ = nlist_merge(*rows, zero, zero, zero,
+                                        jnp.int32(1), interpret=True)
+    cmps, checks = np.asarray(cmps), np.asarray(checks)
+    assert not cmps.any()
+    assert not fetched_codes(cmps - checks, checks, zero, zero, w, w).any()
 
 
 def _zmerge_loop(out_slot, u_freq, v_pre, v_post, u_len):

@@ -102,7 +102,22 @@ def test_mine_packed_matches_reference_and_oracle_counts(backend,
                                               ost.es_checks)
     assert st.candidates == ost.candidates
     assert 0 < st.nl_codes <= st.nl_gather_codes
+    assert 0 < st.nl_fetch_codes <= st.nl_gather_codes
     assert st.build_s > 0
+
+
+def test_nl_fetch_codes_counts_part_of_the_gathered_rows():
+    """``nl_fetch_codes`` (the operand codes the merge kernel copies into
+    scalar memory) on a database whose N-lists fill rows of 128 codes and
+    more, which the kernel fetches in blocks: more than none, fewer than
+    were gathered (merges cut short and padding pairs fetch part of their
+    rows or none), and in ``as_dict``."""
+    db, minsup = _dense(0, n_trans=1200, n_cols=12), 480
+    bdb = BitmapDB.from_db(db, minsup)
+    assert build_nlists(bdb, minsup).lengths.max() >= 128
+    _, st = DevicePrePost(pair_chunk=64).mine_packed(bdb, minsup)
+    assert 0 < st.nl_fetch_codes < st.nl_gather_codes
+    assert st.as_dict()["nl_fetch_codes"] == st.nl_fetch_codes
 
 
 def test_mine_is_packing_then_mine_packed():
