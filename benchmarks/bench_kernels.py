@@ -87,37 +87,6 @@ def bench_fused_store(n_pairs=4096, n_blocks=8, bw=128) -> List[str]:
             f"{dt*1e6:.0f},Gword_s={words/dt/1e9:.2f}"]
 
 
-def bench_attention(B=2, S=1024, H=8, KH=2, D=64) -> List[str]:
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, KH, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, KH, D)), jnp.float32)
-    flops = 4.0 * B * S * S * H * D / 2  # causal
-
-    from repro.models.layers import chunked_attention
-    f = jax.jit(lambda q, k, v: chunked_attention(q, k, v, causal=True,
-                                                  chunk=256))
-    dt = _timeit(f, q, k, v)
-    out = [f"kernels/chunked_attention/B{B}S{S}H{H},"
-           f"{dt*1e6:.0f},GFLOP_s={flops/dt/1e9:.1f}"]
-    dt = _timeit(jax.jit(lambda q, k, v: ops.flash_attention(
-        q, k, v, causal=True, backend='jnp')), q, k, v)
-    out.append(f"kernels/attention_ref/B{B}S{S}H{H},"
-               f"{dt*1e6:.0f},GFLOP_s={flops/dt/1e9:.1f}")
-    return out
-
-
-def bench_embedding_bag(V=100_000, D=64, B=4096, L=50) -> List[str]:
-    rng = np.random.default_rng(2)
-    table = jnp.asarray(rng.normal(size=(V, D)), jnp.float32)
-    ids = jnp.asarray(rng.integers(0, V, (B, L)), jnp.int32)
-    mask = jnp.asarray(rng.random((B, L)) < 0.9)
-    f = jax.jit(lambda t, i, m: ops.embedding_bag(t, i, m, backend="jnp"))
-    dt = _timeit(f, table, ids, mask)
-    return [f"kernels/embedding_bag/V{V}D{D}B{B}L{L},"
-            f"{dt*1e6:.0f},Mlookup_s={B*L/dt/1e6:.1f}"]
-
-
 def bench_nlist(n_pairs=2048, lu=64, lv=64) -> List[str]:
     rng = np.random.default_rng(3)
     def mk(n, L):

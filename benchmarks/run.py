@@ -1,8 +1,7 @@
 """Benchmark harness: one section per paper table/figure + kernel micro.
 
-Prints ``name,us_per_call,derived`` CSV.  ``--quick`` limits the paper
-sweep to the two largest minsups per dataset (the full ladder is the
-``--full`` mode used for EXPERIMENTS.md).
+Prints ``name,us_per_call,derived`` CSV.  By default the paper sweep
+runs the two largest minsups per dataset; ``--full`` runs the ladder.
 """
 
 from __future__ import annotations
@@ -17,13 +16,11 @@ def main() -> None:
                     help="all 4 minsup levels on all 9 datasets")
     ap.add_argument("--datasets", default="",
                     help="comma-separated replica names (default: all)")
-    ap.add_argument("--sections", default="paper,kernels,retrieval")
-    ap.add_argument("--retrieval-c", type=int, default=250_000)
+    ap.add_argument("--sections", default="paper,kernels")
     args = ap.parse_args()
     sections = set(args.sections.split(","))
 
     print("name,us_per_call,derived")
-    lines = []
 
     if "paper" in sections:
         from benchmarks.bench_paper import run_dataset, csv_rows, \
@@ -45,20 +42,9 @@ def main() -> None:
         print(figures(all_rows), file=sys.stderr)
 
     if "kernels" in sections:
-        from benchmarks.bench_kernels import (bench_bitmap, bench_attention,
-                                              bench_embedding_bag,
-                                              bench_nlist)
-        for line in (bench_bitmap() + bench_attention()
-                     + bench_embedding_bag() + bench_nlist()):
+        from benchmarks.bench_kernels import bench_bitmap, bench_nlist
+        for line in bench_bitmap() + bench_nlist():
             print(line)
-
-    if "retrieval" in sections:
-        from benchmarks.bench_retrieval import run as bench_retrieval
-        for line in bench_retrieval(C=args.retrieval_c):
-            print(line)
-
-    for line in lines:
-        print(line)
 
 
 if __name__ == "__main__":

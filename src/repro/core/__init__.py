@@ -22,6 +22,5 @@ from repro.core.eclat import (  # noqa: F401
 )
 from repro.core.prepost import DevicePrePost, mine_prepost_device  # noqa: F401
 from repro.core.distributed import (  # noqa: F401
-    DistributedMiner, DistributedStats, make_mining_round,
-    make_mining_round_v2,
+    DistributedMiner, DistributedStats,
 )

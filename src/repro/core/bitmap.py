@@ -4,7 +4,7 @@ The paper's sorted-int TID-lists are pointer-chasing merges; on TPU we
 re-represent every TID-list as a packed bitmap row so that intersection is
 ``AND`` + popcount (pure 8x128-lane VPU work) and dEclat's difference is
 ``ANDNOT``.  The early-stopping criterion survives the translation at block
-granularity via per-row *suffix popcount* tables (see DESIGN.md §2).
+granularity via per-row *suffix popcount* tables (see docs/ARCHITECTURE.md).
 
 Layout
 ------
@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Dict, Hashable, List, Sequence, Tuple
+from typing import Dict, Hashable, List, Sequence
 
 import numpy as np
 
@@ -298,21 +298,3 @@ class BitmapDB:
         supports = np.array([len(t) for t in tidlists], dtype=np.int32)
         return cls(items=items, bitmaps=bitmaps, supports=supports,
                    n_trans=len(db), minsup=minsup, block_words=block_words)
-
-
-def pad_pairs(ia: np.ndarray, ib: np.ndarray, bucket_sizes: Sequence[int],
-              ) -> Tuple[np.ndarray, np.ndarray, int]:
-    """Pad pair index vectors to the smallest bucket >= n (stable jit shapes).
-
-    Padding replicates pair 0 (harmless: results beyond ``n`` are dropped by
-    the caller).  Returns (ia_padded, ib_padded, n_valid)."""
-    n = int(ia.shape[0])
-    for b in bucket_sizes:
-        if n <= b:
-            pad = b - n
-            if pad:
-                ia = np.concatenate([ia, np.zeros(pad, ia.dtype)])
-                ib = np.concatenate([ib, np.zeros(pad, ib.dtype)])
-            return ia, ib, n
-    raise ValueError(f"pair batch of {n} exceeds largest bucket "
-                     f"{max(bucket_sizes)}; raise pair_chunk")

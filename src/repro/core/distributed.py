@@ -1,6 +1,6 @@
 """Distributed mining: count distribution over the TID axis.
 
-Scaling story (DESIGN.md §2.4): transactions (TID bitmap *blocks*) are
+Scaling story (docs/ARCHITECTURE.md): transactions (TID bitmap *blocks*) are
 sharded across the mesh axes; candidate pairs are replicated.  Each
 device computes partial popcounts over its block shard; one ``psum`` of
 ``int32[n_pairs]`` vectors produces global bounds/supports.  The
@@ -38,10 +38,6 @@ materialization**: every shard knows the global count/alive before its
 shard-local scatter phase, so a candidate the screen or scan killed is
 never written to the slab — child scatter traffic scales with frequent
 children, not candidates (``stats.child_scatters``).
-
-``make_mining_round`` / ``make_mining_round_v2`` remain: they are the
-standalone round programs used by the dry-run/roofline harness (cost
-analysis wants an isolated lowerable SPMD program, not a live miner).
 """
 
 from __future__ import annotations
@@ -50,131 +46,17 @@ from typing import Tuple
 
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from repro.compat import shard_map
-from repro.core.bitmap import DEFAULT_BLOCK_WORDS, BitmapDB, popcount32
+from repro.core.bitmap import DEFAULT_BLOCK_WORDS, BitmapDB
 from repro.core.eclat import (BitmapMiner, DeviceMiningStats, _bucket_pad,
-                              _count_lanes, _read_dispatch,
-                              ItemsetSupports)  # noqa: F401 (re-export)
+                              _count_lanes, _read_dispatch)
 from repro.core.rowstore import DeviceRowStore
 from repro.kernels import ops
 
 # Back-compat alias: the unified engine reports the same stats object as
 # the single-device miner (``rounds`` became ``device_calls``).
 DistributedStats = DeviceMiningStats
-
-
-# ---------------------------------------------------------------------------
-# Standalone round programs (dry-run / roofline harness)
-# ---------------------------------------------------------------------------
-
-def _local_suffix(bitmaps: jnp.ndarray) -> jnp.ndarray:
-    """Suffix popcounts over the LOCAL block shard: (rows, nb_local+1)."""
-    per_block = popcount32(bitmaps).sum(axis=-1).astype(jnp.int32)
-    rev = jnp.cumsum(per_block[:, ::-1], axis=1)[:, ::-1]
-    zeros = jnp.zeros((bitmaps.shape[0], 1), jnp.int32)
-    return jnp.concatenate([rev, zeros], axis=1)
-
-
-def make_mining_round(mesh: Mesh, *, pair_chunk: int = 2048):
-    """Fused screen+count round used by the dry-run/roofline harness.
-
-    Pure Count Distribution (Agrawal & Shafer '96 adapted to Eclat): the
-    bitmap store's BLOCK axis is sharded across EVERY mesh axis (a 1.07B-
-    transaction store is 4GB/chip on 256 chips); the candidate pair list
-    is replicated; each chip computes partial popcounts + local suffix
-    screen bounds on its block shard, and one psum of two int32[n_pairs]
-    vectors produces global bounds/counts.  The transaction data never
-    moves.
-
-    Pairs are processed in ``pair_chunk``-sized slices with ``lax.scan``
-    so per-chunk gather buffers are provably reused (an unrolled loop let
-    the scheduler keep every chunk's 3GB gather alive).  cost_analysis
-    counts the scan body once; the dry-run reconstructs totals from two
-    reduced-pair compiles (same fit as the LM cells)."""
-    all_axes = tuple(mesh.axis_names)
-    tid_spec = all_axes if len(all_axes) > 1 else all_axes[0]
-
-    def mining_round(store, pairs, rho):
-        del rho
-        n = pairs.shape[0]
-        chunk = min(pair_chunk, n)
-        pc = pairs.reshape(n // chunk, chunk, 2)
-
-        def body(_, p):
-            u = store[p[:, 0]]            # (chunk, nb_local, bw)
-            v = store[p[:, 1]]
-            z0 = u[:, 0] & v[:, 0]
-            c0 = popcount32(z0).sum(axis=-1)
-            su = _local_suffix(u)[:, 1]
-            sv = _local_suffix(v)[:, 1]
-            bound_c = c0 + jnp.minimum(su, sv)
-            count_c = popcount32(u & v).sum(axis=(-1, -2))
-            return None, (bound_c, count_c)
-
-        _, (bounds, counts) = jax.lax.scan(body, None, pc)
-        bound = jax.lax.psum(bounds.reshape(n), all_axes)
-        count = jax.lax.psum(counts.reshape(n), all_axes)
-        return bound, count
-
-    return shard_map(
-        mining_round, mesh=mesh,
-        in_specs=(P(None, tid_spec, None), P(None, None), P(None)),
-        out_specs=(P(None), P(None)), check_vma=False)
-
-
-def make_mining_round_v2(mesh: Mesh, *, pair_chunk: int = 2048):
-    """Optimised mining round (hillclimb variant, EXPERIMENTS.md §Perf).
-
-    Two changes over ``make_mining_round``, both beyond-paper engineering
-    on top of the paper's criterion:
-
-      1. PRECOMPUTED shard-local suffix masses: the baseline recomputes
-         each operand's suffix popcounts from its full gathered row (per
-         pair!).  The mass "popcount of blocks 1.. on shard s" is a
-         per-(row, shard) invariant maintained when rows materialise, so
-         the round takes it as ``suffix1 (rows, n_shards)`` (each shard
-         owns its column) and the screen touches only block 0 + one
-         scalar per operand.
-      2. SHARED-``a`` chunking: the host already batches sibling pairs of
-         one class member 'a'; with ``pairs[c, :, 0]`` constant per chunk
-         the u-row is gathered ONCE per chunk instead of per pair
-         (u-traffic / pair_chunk).
-    """
-    all_axes = tuple(mesh.axis_names)
-    tid_spec = all_axes if len(all_axes) > 1 else all_axes[0]
-
-    def mining_round(store, suffix1, pairs, rho):
-        del rho
-        n = pairs.shape[0]
-        chunk = min(pair_chunk, n)
-        pc = pairs.reshape(n // chunk, chunk, 2)
-
-        def body(_, p):
-            a_row = p[0, 0]               # shared-'a' chunk
-            u = store[a_row]              # (nb_local, bw) — ONE gather
-            su = suffix1[a_row, 0]        # local column of this shard
-            v = store[p[:, 1]]            # (chunk, nb_local, bw)
-            sv = suffix1[p[:, 1], 0]
-            z0 = u[0][None] & v[:, 0]
-            c0 = popcount32(z0).sum(axis=-1)
-            bound_c = c0 + jnp.minimum(su, sv)
-            count_c = popcount32(u[None] & v).sum(axis=(-1, -2))
-            return None, (bound_c, count_c)
-
-        _, (bounds, counts) = jax.lax.scan(body, None, pc)
-        bound = jax.lax.psum(bounds.reshape(n), all_axes)
-        count = jax.lax.psum(counts.reshape(n), all_axes)
-        return bound, count
-
-    return shard_map(
-        mining_round, mesh=mesh,
-        in_specs=(P(None, tid_spec, None), P(None, tid_spec), P(None, None),
-                  P(None)),
-        out_specs=(P(None), P(None)), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -203,8 +85,7 @@ class DistributedMiner(BitmapMiner):
     (maximum block parallelism); ``cls_axes`` defaults to ``("cls",)``
     when the mesh has an axis of that name (the ``make_mining_mesh``
     convention) and to none otherwise.  ``capacity`` is an initial-size
-    hint only: the slab grows instead of raising.  ``pair_axis`` is
-    accepted for backward compatibility and ignored — pairs are
+    hint only: the slab grows instead of raising.  Pairs are
     replicated over the block axes; under a 2-D mesh (ISSUE 9) each
     cls-shard evaluates its contiguous slice of the chunk's pair
     vectors, so the psum'd per-pair vectors shrink by n_cls and the
@@ -214,7 +95,6 @@ class DistributedMiner(BitmapMiner):
     def __init__(self, mesh: Mesh, *,
                  tid_axes: Tuple[str, ...] = None,
                  cls_axes: Tuple[str, ...] = None,
-                 pair_axis: str = None,
                  scheme: str = "eclat",
                  early_stop: bool = True,
                  capacity: int = 4096, pair_chunk: int = 4096,
@@ -231,7 +111,6 @@ class DistributedMiner(BitmapMiner):
                          diff_hysteresis=diff_hysteresis,
                          inflight=inflight,
                          autotune_chunk=autotune_chunk)
-        del pair_axis
         self.mesh = mesh
         if cls_axes is None:
             # make_mining_mesh names its pair axis "cls"; honour that by

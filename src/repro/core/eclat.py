@@ -1,6 +1,6 @@
 """Device bitmap miners: Eclat and dEclat with block-level early stopping.
 
-Host/DFS split (DESIGN.md §2): the equivalence-class depth-first search
+Host/DFS split (docs/ARCHITECTURE.md): the equivalence-class depth-first search
 stays on the host (Python), but the host only ever handles row *indices*
 and small int vectors — every bitmap row lives in a device-resident
 ``DeviceRowStore`` slab (core/rowstore.py) from the moment the level-1

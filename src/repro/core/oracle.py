@@ -6,8 +6,8 @@ Early-Stopping (ES) variants, with per-call comparison counters so the
 paper's headline metric (#comparisons) is reproducible bit-for-bit.
 
 These are the ground-truth oracles for the TPU bitmap engine in
-``repro.core.eclat`` / ``repro.core.declat`` / ``repro.core.prepost`` and
-the source of the benchmark numbers in EXPERIMENTS.md §Paper.
+``repro.core.eclat`` / ``repro.core.prepost``: the tests compare every
+engine with them.
 
 Conventions
 -----------
@@ -447,14 +447,15 @@ def _nl_intersect_es(U: List[PPCode], V: List[PPCode], rho_v: int,
     its frequency mass from the achievable support; abort when the bound
     drops below minsup (returns the empty N-list, support 0).
 
-    PAPER ERRATUM (documented in DESIGN.md §Errata): as printed, the
-    criterion is ``rho_V - skip < minSup`` with ``skip`` accumulated on
-    *every* j-advance.  Because j only ever advances through the skip
-    branch, a V-code that already contributed matches to Z also lands in
-    ``skip``, so the printed bound ignores support mass that has already
-    been earned and can abort a *frequent* candidate (it is only exact
-    when Z is empty at check time, as in the paper's Example 4.2).  The
-    sound version of the same idea — which we implement — is
+    PAPER ERRATUM: as printed, the criterion is
+    ``rho_V - skip < minSup`` with ``skip`` accumulated on *every*
+    j-advance.  Because j only ever advances through the skip branch,
+    a V-code that already contributed matches to Z also lands in
+    ``skip``, so the printed bound ignores support mass that has
+    already been earned and can abort a *frequent* candidate (it is
+    only exact when Z is empty at check time, as in the paper's
+    Example 4.2).  The sound version of the same idea — which we
+    implement — is
 
         z_mass + (rho_V - skip) < minSup
 

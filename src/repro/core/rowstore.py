@@ -1,6 +1,6 @@
 """Device-resident bitmap row store with a host-managed free list.
 
-The frontier engine's hot-path data structure (DESIGN.md §2, ISSUE 1):
+The frontier engine's hot-path data structure (docs/ARCHITECTURE.md):
 every TID bitmap / diffset row that the DFS can still touch lives in one
 preallocated device slab ``uint32[capacity, n_blocks, block_words]`` with
 a parallel suffix-popcount slab.  The host never sees row *contents* — it

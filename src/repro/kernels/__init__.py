@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels of the mining hot path (bitmap intersect/diff, N-list
+# merge, slab compaction), their dispatch wrappers (ops.py) and the
+# pure-jnp oracles each is pinned to (ref.py).

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: blocked bitmap intersection with early stopping.
 
 This is the paper's contribution lowered to the TPU execution model
-(DESIGN.md §2): TID-lists are packed ``uint32`` bitmap rows, intersection
+(docs/ARCHITECTURE.md): TID-lists are packed ``uint32`` bitmap rows, intersection
 is ``AND`` (+ ``ANDNOT`` for dEclat diffsets) + SWAR popcount on the VPU,
 and the Early-Stopping criterion is evaluated once per *block* using
 precomputed suffix-popcount tables.  A pair that is provably infrequent

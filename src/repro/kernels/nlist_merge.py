@@ -1,10 +1,11 @@
 """Pallas TPU kernel: batched PrePost+ N-list merge with early stopping.
 
-The sequential heart of ``ops.nlist_extend`` (DESIGN.md §Errata for the
-ES criterion): one grid program per candidate pair walks the two operand
-PP-code lists with a two-pointer ``lax.while_loop``, records which V
-ancestor code each U code matched (``out_slot``), and aborts the moment
-the corrected bound ``z_mass + (rho_V - skip)`` drops below minsup.
+The sequential heart of ``ops.nlist_extend`` (the ES criterion's erratum
+is at ``core/oracle.py::_nl_intersect_es``): one grid program per
+candidate pair walks the two operand PP-code lists with a two-pointer
+``lax.while_loop``, records which V ancestor code each U code matched
+(``out_slot``), and aborts the moment the corrected bound
+``z_mass + (rho_V - skip)`` drops below minsup.
 
 Grid/layout mirrors ``bitmap_intersect.py``: one program per pair.  The
 merge reads and writes single codes at data-dependent indices, which is

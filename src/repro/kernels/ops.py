@@ -31,8 +31,8 @@ over ``NL_LEN_BUCKETS``.  Per-bucket autotuned chunk widths
 (``chunk_width_for``) stay inside the same tables — autotuning changes
 which bucket a chunk lands in, never introduces new shapes — so the
 cache holds at most one entry per (width-bucket, op) pair regardless
-of the width policy.  (Not asserted here: tests and the roofline
-harness call these wrappers directly with arbitrary widths; the
+of the width policy.  (Not asserted here: tests and the kernel
+benchmarks call these wrappers directly with arbitrary widths; the
 discipline is the engines' contract, enforced by their use of
 ``bucket_pad``.)
 
@@ -530,29 +530,6 @@ def screen_pairs(first_u, first_v, suffix1_u, suffix1_v, rho_parent, minsup,
     del backend  # single cheap fused op; jnp path is optimal everywhere
     return _ref.screen_pairs_ref(first_u, first_v, suffix1_u, suffix1_v,
                                  rho_parent, minsup, mode=mode)
-
-
-def flash_attention(q, k, v, *, causal: bool = True, softmax_scale=None,
-                    backend: str = "auto"):
-    """Fused attention: Pallas kernel on TPU, dense ref elsewhere."""
-    b = _resolve(backend)
-    if b in _PALLAS:
-        from .flash_attention import flash_attention as _fa
-        return _fa(q, k, v, causal=causal, softmax_scale=softmax_scale,
-                   interpret=b == "interpret")
-    return _ref.flash_attention_ref(q, k, v, causal=causal,
-                                    softmax_scale=softmax_scale)
-
-
-def embedding_bag(table, ids, mask, *, combiner: str = "mean",
-                  backend: str = "auto"):
-    """Fused EmbeddingBag: Pallas on TPU, take+reduce elsewhere."""
-    b = _resolve(backend)
-    if b in _PALLAS:
-        from .segment_embed import embedding_bag as _eb
-        return _eb(table, ids, mask, combiner=combiner,
-                   interpret=b == "interpret")
-    return _ref.embedding_bag_ref(table, ids, mask, combiner=combiner)
 
 
 def nlist_intersect(u_pre, u_post, u_freq, v_pre, v_post, v_freq,

@@ -546,38 +546,6 @@ def compact_gather_ref(slab: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# flash attention + embedding bag oracles
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("causal",))
-def flash_attention_ref(q, k, v, *, causal: bool = True,
-                        softmax_scale=None) -> jnp.ndarray:
-    """Dense reference attention (fp32 softmax), GQA by kv-head repeat."""
-    B, Sq, H, D = q.shape
-    _, Skv, KH, Dv = v.shape
-    G = H // KH
-    scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    qg = q.reshape(B, Sq, KH, G, D).astype(jnp.float32)
-    s = jnp.einsum("bqkgd,bskd->bqkgs", qg, k.astype(jnp.float32)) * scale
-    if causal:
-        mask = jnp.arange(Sq)[:, None] >= jnp.arange(Skv)[None, :]
-        s = jnp.where(mask[None, :, None, None, :], s, -1e30)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bqkgs,bskv->bqkgv", a, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, Dv).astype(q.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("combiner",))
-def embedding_bag_ref(table, ids, mask, *, combiner: str = "mean"):
-    e = jnp.take(table, ids, axis=0)                 # (B, L, D)
-    m = mask.astype(jnp.float32)[..., None]
-    s = (e.astype(jnp.float32) * m).sum(axis=-2)
-    if combiner == "mean":
-        s = s / jnp.maximum(m.sum(axis=-2), 1.0)
-    return s.astype(table.dtype)
-
-
-# ---------------------------------------------------------------------------
 # N-list intersection (PrePost+) — device variant
 # ---------------------------------------------------------------------------
 #

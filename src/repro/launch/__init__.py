@@ -1,1 +1,2 @@
-# Launch layer: production mesh, cell builders, dry-run, train/serve drivers.
+# Launch layer: the (block, cls) mining mesh, forced CPU device counts
+# for multi-device tests, and the multi-host bootstrap.

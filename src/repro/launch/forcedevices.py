@@ -1,10 +1,8 @@
 """Force a CPU host to present N virtual devices (ISSUE 9, satellite 2).
 
-``launch/dryrun.py`` hard-codes ``XLA_FLAGS`` at module top for its own
-512-way sweep; this module is the reusable version for the mining test
-harness: subprocess tests call :func:`force_host_device_count` *before*
-importing anything that touches a jax backend, then build a real 2-D
-``(block, cls)`` mesh over the virtual devices.
+Subprocess tests and examples call :func:`force_host_device_count`
+*before* importing anything that touches a jax backend, then build a
+real 2-D ``(block, cls)`` mesh over the virtual devices.
 
 Import of this module itself is backend-safe: ``repro``/``repro.launch``
 ``__init__`` files import nothing, so

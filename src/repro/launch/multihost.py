@@ -1,28 +1,19 @@
 """Multi-host bootstrap for real TPU pods.
 
 On-cluster entry point: every host calls ``init_distributed()`` before
-any other jax usage; the coordinator address/process indices come from
-the TPU metadata environment (GKE/TPU-VM set these) or explicit flags.
-After init, ``jax.devices()`` spans the whole slice and the exact same
-``make_production_mesh()`` / cell-builder code used by the CPU dry-run
-drives real silicon — that equivalence is the point of the dry-run.
-
-Fault tolerance at this layer (DESIGN.md §6):
-  * restartable: training state lives in mesh-agnostic checkpoints; any
-    replacement host set re-initialises and restores (elastic pod count);
-  * deterministic data: every host regenerates its shard of any global
-    batch from (seed, step) — no data-service handoff on failover;
-  * straggler detection: a lightweight heartbeat barrier each
-    ``--heartbeat-every`` steps; hosts that miss ``--max-missed``
-    heartbeats trigger a controlled save-and-exit so the scheduler can
-    reschedule the slice (preemption-safe).
+any other jax usage (``make_mining_mesh(multihost=True)`` does); the
+coordinator address and process indices come from explicit arguments or
+the ``COORDINATOR_ADDRESS`` / ``NUM_PROCESSES`` / ``PROCESS_ID``
+environment.  After init, ``jax.devices()`` spans the whole slice and
+the same ``make_mining_mesh()`` used on one host lays the ``(block,
+cls)`` mesh over it.  ``python -m repro.launch.multihost`` is a
+smoke test: init, print the device view, one psum barrier.
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import time
 from typing import Optional
 
 import jax
@@ -34,36 +25,13 @@ def init_distributed(coordinator: Optional[str] = None,
     """Initialise jax.distributed from flags or scheduler environment."""
     coordinator = coordinator or os.environ.get("COORDINATOR_ADDRESS")
     if coordinator is None:
-        # single-host run (tests / CPU dry-run): nothing to do
+        # single-host run (tests, one chip): nothing to do
         return
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=int(num_processes
                           or os.environ.get("NUM_PROCESSES", 1)),
         process_id=int(process_id or os.environ.get("PROCESS_ID", 0)))
-
-
-class Heartbeat:
-    """Cross-host liveness barrier: a tiny psum each interval; a timeout
-    means a peer is gone or wedged -> save and exit non-zero so the
-    scheduler restarts the slice from the latest checkpoint."""
-
-    def __init__(self, interval_steps: int = 100, timeout_s: float = 300.0):
-        self.interval = interval_steps
-        self.timeout = timeout_s
-        self._last = time.time()
-
-    def maybe_beat(self, step: int, on_failure=None) -> None:
-        if step % self.interval:
-            return
-        try:
-            # an all-reduce over one scalar doubles as the barrier
-            jax.device_get(_psum_one())
-            self._last = time.time()
-        except Exception:
-            if on_failure is not None:
-                on_failure()
-            raise
 
 
 def _psum_one():

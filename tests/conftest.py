@@ -4,8 +4,8 @@ import types
 
 import pytest
 
-# Tests must see the real device set (1 CPU device) — the 512-device flag
-# belongs to the dry-run process only (launch/dryrun.py).
+# Tests see the real device set (1 CPU device); multi-device tests force
+# more in a subprocess (repro.launch.forcedevices).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # ---------------------------------------------------------------------------

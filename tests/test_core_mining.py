@@ -63,7 +63,7 @@ def test_bitmap_word_ops_savings_on_sparse_data():
 
 def test_distributed_screen_bound_tighter_than_central():
     """Sum of per-shard minima <= min of sums: the distributed screen is
-    at least as tight as the centralized bound (DESIGN.md §2.4)."""
+    at least as tight as the centralized bound (docs/ARCHITECTURE.md)."""
     import numpy as np
     from repro.core.bitmap import popcount32_np
     rng = np.random.default_rng(0)

@@ -1,141 +1,45 @@
-"""End-to-end integration: train driver (ckpt/restart), serve driver,
-and the dry-run machinery on a small subprocess mesh."""
+"""End-to-end: the ``repro-mine`` CLI on every scheme, engine and ES
+setting it offers, each mining a FIMI file through ``--input``."""
 
-import subprocess
+import json
+import random
 import sys
-import textwrap
 
-import numpy as np
 import pytest
-import dataclasses
 
-from repro.configs import get_arch
-
-
-@pytest.mark.slow
-def test_train_lm_loss_decreases_and_resumes(tmp_path):
-    from repro.launch.train import train_lm
-
-    cfg = dataclasses.replace(
-        get_arch("qwen1.5-0.5b").smoke_config_fn(),
-        n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_head=16,
-        d_ff=128, vocab_size=512, attn_chunk=32)
-    d = str(tmp_path / "ckpt")
-    out = train_lm(cfg, steps=30, batch=4, seq_len=64, lr=5e-3,
-                   ckpt_dir=d, ckpt_every=10, log_every=10,
-                   log_fn=lambda *a: None)
-    first = out["history"][0][1]
-    final = out["final"]["loss"]
-    assert final < first, (first, final)
-
-    # resume continues from step 30 and trains further without blowup
-    out2 = train_lm(cfg, steps=40, batch=4, seq_len=64, lr=5e-3,
-                    ckpt_dir=d, ckpt_every=10, resume=True, log_every=5,
-                    log_fn=lambda *a: None)
-    assert out2["history"][0][0] > 30   # started past the restore point
-    assert np.isfinite(out2["final"]["loss"])
+from repro.core import cli
+from repro.core.oracle import mine_bruteforce
 
 
-def test_serve_greedy_deterministic():
-    from repro.launch.serve import serve_greedy
-
-    cfg = get_arch("qwen1.5-0.5b").smoke_config_fn()
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size, (2, 16)).astype(np.int32)
-    a = serve_greedy(cfg, prompts, max_new=4, seed=1,
-                     log_fn=lambda *a: None)
-    b = serve_greedy(cfg, prompts, max_new=4, seed=1,
-                     log_fn=lambda *a: None)
-    assert np.array_equal(a, b)
-    assert a.shape == (2, 4)
+def _fimi_db(seed=11, n_items=7, n_trans=40):
+    rng = random.Random(seed)
+    db = [sorted(i for i in range(1, n_items + 1) if rng.random() < 0.45)
+          for _ in range(n_trans)]
+    return [t for t in db if t]
 
 
-DRYRUN_SMALL = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    import sys
-    sys.path.insert(0, "src")
-    import jax
-    from repro.compat import make_mesh
-    mesh = make_mesh((4, 4), ("data", "model"))
-    import repro.launch.dryrun as DR
-    rec = DR.run_cell("qwen1.5-0.5b", "decode_32k", mesh, "test4x4",
-                      "/tmp/dryrun_test_ci")
-    assert rec.get("ok"), rec.get("error")
-    assert rec["cost_analysis"]["flops"] > 0
-    assert rec["collectives"]["total"]["count"] >= 0
-    assert rec["peak_memory_per_chip"] > 0
-    # cost fit present for LM cells (scan reconstruction)
-    assert "cost_fit" in rec and rec["cost_fit"]["n_layers_extrapolated"] == 24
-    print("DRYRUN_SMALL_OK")
-""")
-
-
-@pytest.mark.slow
-def test_dryrun_machinery_small_mesh():
-    proc = subprocess.run([sys.executable, "-c", DRYRUN_SMALL],
-                          capture_output=True, text=True, timeout=900,
-                          cwd=".")
-    assert "DRYRUN_SMALL_OK" in proc.stdout, proc.stderr[-3000:]
-
-
-def test_roofline_terms_math():
-    from repro.roofline.analysis import RooflineTerms, PEAK_FLOPS, HBM_BW
-
-    t = RooflineTerms(arch="a", shape="train_x", mesh="m", chips=256,
-                      flops_per_chip=PEAK_FLOPS,      # exactly 1s compute
-                      bytes_per_chip=HBM_BW * 0.5,    # 0.5s memory
-                      link_bytes_per_chip=0.0,
-                      model_flops=0.5 * 256 * PEAK_FLOPS)
-    assert t.t_compute == pytest.approx(1.0)
-    assert t.t_memory == pytest.approx(0.5)
-    assert t.bottleneck == "compute"
-    assert t.step_time_lower_bound == pytest.approx(1.0)
-    assert t.useful_flops_ratio == pytest.approx(0.5)
-    assert t.roofline_fraction == pytest.approx(0.5)
-
-
-def test_hlo_collective_parser():
-    from repro.roofline.hlo import parse_collectives, _shape_bytes
-
-    hlo = '''
-      %p0 = f32[16,128]{1,0} parameter(0)
-      %ag = f32[16,2048]{1,0} all-gather(%p0), dimensions={1}
-      %ar = bf16[4,256]{1,0} all-reduce(%p1), to_apply=%add
-      %cp = f32[8]{0} collective-permute(%p2)
-    '''
-    out = parse_collectives(hlo)
-    assert out["all-gather"]["count"] == 1
-    # operand of the all-gather is p0: 16*128*4 bytes
-    assert out["all-gather"]["operand_bytes"] == 16 * 128 * 4
-    assert out["all-reduce"]["count"] == 1
-    assert out["total"]["count"] == 3
-    assert _shape_bytes("bf16[4,256]") == 4 * 256 * 2
-
-
-def test_mining_cli(tmp_path, capsys):
-    """The CLI mines a FIMI file and both engines agree."""
-    import sys as _sys
-    from repro.core import cli
-
+@pytest.mark.parametrize("es", ["--es", "--no-es"])
+@pytest.mark.parametrize("engine", ["oracle", "bitmap"])
+@pytest.mark.parametrize("scheme", ["eclat", "declat", "adaptive", "prepost"])
+def test_mining_cli(tmp_path, monkeypatch, scheme, engine, es):
+    """The CLI reads a FIMI file and its ``--json-out`` map is exactly
+    the brute-force oracle's, whatever path mined it."""
+    db = _fimi_db()
     f = tmp_path / "db.dat"
-    f.write_text("1 2 3\n1 2\n2 3\n1 2 3 4\n2 4\n")
-    outs = {}
-    for engine in ("oracle", "bitmap"):
-        _sys.argv = ["cli", "--input", str(f), "--minsup", "2",
-                     "--engine", engine,
-                     "--json-out", str(tmp_path / f"{engine}.json")]
-        cli.main()
-        import json as _json
-        outs[engine] = _json.load(open(tmp_path / f"{engine}.json"))
-    assert outs["oracle"] == outs["bitmap"]
-    assert outs["oracle"]["2"] == 5
-    # adaptive scheme + its knobs flow through to the bitmap engine
-    # (oracle has no adaptive mode: the CLI maps it to eclat there)
-    _sys.argv = ["cli", "--input", str(f), "--minsup", "2",
-                 "--engine", "bitmap", "--scheme", "adaptive",
-                 "--block-words", "1", "--diff-density", "0.3",
-                 "--diff-hysteresis", "0.05",
-                 "--json-out", str(tmp_path / "adaptive.json")]
+    f.write_text("".join(" ".join(map(str, t)) + "\n" for t in db))
+    out = tmp_path / "out.json"
+    argv = ["repro-mine", "--input", str(f), "--minsup", "6",
+            "--scheme", scheme, "--engine", engine, es,
+            "--json-out", str(out)]
+    if scheme == "adaptive":
+        # the adaptive knobs flow through to the bitmap engine (the
+        # oracle has no adaptive mode: the CLI maps it to eclat there)
+        argv += ["--block-words", "1", "--diff-density", "0.3",
+                 "--diff-hysteresis", "0.05"]
+    monkeypatch.setattr(sys, "argv", argv)
     cli.main()
-    assert _json.load(open(tmp_path / "adaptive.json")) == outs["oracle"]
+
+    want = {",".join(str(i) for i in sorted(s, key=str)): c
+            for s, c in mine_bruteforce(db, 6).items()}
+    assert any(k.count(",") >= 2 for k in want)   # reaches depth 3
+    assert json.loads(out.read_text()) == want

@@ -10,13 +10,10 @@ from repro.core.bitmap import (pack_tidlists, suffix_popcounts_np,
 from repro.kernels import ops
 from repro.kernels.ref import (bitmap_intersect_es_ref, bitmap_diff_es_ref,
                                bitmap_intersect_full_ref, bitmap_count_ref,
-                               flash_attention_ref, embedding_bag_ref,
                                screen_pairs_ref, screen_and_intersect_ref,
                                screen_and_diff_ref, NL_SENTINEL)
 from repro.kernels.bitmap_diff import bitmap_diff_es
 from repro.kernels.nlist_merge import B_MAX
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.segment_embed import embedding_bag
 
 
 # ---------------------------------------------------------------------------
@@ -678,55 +675,3 @@ def test_nl_write_matches_per_code_scatter(cap, lu):
                     jnp.asarray(child_len), jnp.asarray(out_off))
     assert out_off[0] > cap - min(lu, cap)      # the clamped window
     assert np.array_equal(np.asarray(got), np.asarray(want))
-
-
-# ---------------------------------------------------------------------------
-# flash attention kernel
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize(
-    "B,Sq,Skv,H,KH,D,Dv,causal,dtype,tol",
-    [
-        (2, 128, 128, 4, 2, 32, 32, True, jnp.float32, 2e-5),
-        (1, 256, 256, 8, 8, 64, 64, True, jnp.float32, 2e-5),
-        (2, 128, 256, 4, 1, 32, 16, False, jnp.float32, 2e-5),
-        (1, 128, 128, 4, 4, 128, 128, True, jnp.float32, 2e-5),
-        (1, 128, 128, 4, 2, 32, 32, True, jnp.bfloat16, 3e-2),
-    ])
-def test_flash_attention_sweep(B, Sq, Skv, H, KH, D, Dv, causal, dtype, tol):
-    rng = np.random.default_rng(3)
-    q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), dtype)
-    k = jnp.asarray(rng.normal(size=(B, Skv, KH, D)), dtype)
-    v = jnp.asarray(rng.normal(size=(B, Skv, KH, Dv)), dtype)
-    out = flash_attention(q, k, v, causal=causal, q_block=64, kv_block=64)
-    ref = flash_attention_ref(q, k, v, causal=causal)
-    err = float(jnp.abs(out.astype(jnp.float32)
-                        - ref.astype(jnp.float32)).max())
-    assert err < tol, err
-
-
-# ---------------------------------------------------------------------------
-# embedding bag kernel
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("V,D,B,L,comb", [
-    (100, 16, 8, 5, "mean"), (64, 32, 16, 9, "sum"),
-    (257, 8, 4, 3, "mean"), (1000, 64, 8, 20, "mean"),
-])
-def test_embedding_bag_sweep(V, D, B, L, comb):
-    rng = np.random.default_rng(4)
-    table = jnp.asarray(rng.normal(size=(V, D)), jnp.float32)
-    ids = jnp.asarray(rng.integers(0, V, (B, L)), jnp.int32)
-    mask = jnp.asarray(rng.random((B, L)) < 0.8)
-    out = embedding_bag(table, ids, mask, combiner=comb, bag_block=4)
-    ref = embedding_bag_ref(table, ids, mask, combiner=comb)
-    assert float(jnp.abs(out - ref).max()) < 1e-5
-
-
-def test_embedding_bag_all_masked_bag():
-    table = jnp.ones((8, 4), jnp.float32)
-    ids = jnp.zeros((2, 3), jnp.int32)
-    mask = jnp.asarray([[False] * 3, [True] * 3])
-    out = embedding_bag(table, ids, mask, combiner="mean", bag_block=2)
-    assert float(out[0].sum()) == 0.0
-    assert float(out[1, 0]) == 1.0

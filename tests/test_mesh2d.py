@@ -3,7 +3,7 @@
 In-process tests cover the pieces that don't need multiple devices: the
 sharded ref's cls-slice invariance, the autotune budget division, the
 frontier's chunk-quantum alignment, the mining-mesh builder and the
-CPU dry-run bootstrap.  The real multi-device equivalence sweep —
+forced-device bootstrap.  The real multi-device equivalence sweep —
 frequent sets, supports and every gated EngineAccounting counter
 identical across 1x1 / 8x1 / 1x8 / 4x2 meshes for all schemes, ES
 on/off, serial and pipelined — runs in a subprocess with 8 forced host
